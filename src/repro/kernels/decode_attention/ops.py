@@ -1,4 +1,4 @@
-"""jit'd wrapper for flash-decode: model layout + padding + interpret fallback."""
+"""jit'd wrapper for flash-decode: model layout + padding (TPU, or `interpret=True`)."""
 
 from __future__ import annotations
 
@@ -7,24 +7,22 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import check_backend
 from repro.kernels.decode_attention.decode_attention import flash_decode
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-@functools.partial(jax.jit, static_argnames=("block_k",))
-def decode_attention(q, k, v, n_valid, *, block_k: int = 512):
+@functools.partial(jax.jit, static_argnames=("block_k", "interpret"))
+def decode_attention(q, k, v, n_valid, *, block_k: int = 512, interpret: bool = False):
     """q: (B, Hkv, G, dh); k/v: (B, Hkv, T, dh); n_valid: scalar int32.
 
     Pads the cache length to a block multiple (padding slots are masked by the
     kernel's n_valid comparison, never attended).
     """
+    check_backend(interpret)
     T = k.shape[2]
     bk = min(block_k, T)
     pad = (-T) % bk
     if pad:
         z = ((0, 0), (0, 0), (0, pad), (0, 0))
         k, v = jnp.pad(k, z), jnp.pad(v, z)
-    return flash_decode(q, k, v, n_valid, block_k=bk, interpret=not _on_tpu())
+    return flash_decode(q, k, v, n_valid, block_k=bk, interpret=interpret)

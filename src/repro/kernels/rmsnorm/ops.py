@@ -1,20 +1,21 @@
-"""jit'd wrappers for the fused RMSNorm kernel (rank-agnostic, padding)."""
+"""jit'd wrappers for the fused RMSNorm kernel (rank-agnostic, padding).
+
+TPU only, unless `interpret=True` asks for the Pallas interpreter."""
 
 from __future__ import annotations
 
+import functools
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import check_backend
 from repro.kernels.rmsnorm.rmsnorm import rmsnorm_fwd, rmsnorm_residual_fwd
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-@jax.jit
-def rmsnorm(x, scale):
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def rmsnorm(x, scale, *, interpret: bool = False):
+    check_backend(interpret)
     shape = x.shape
     x2 = x.reshape(-1, shape[-1])
     T = x2.shape[0]
@@ -22,12 +23,13 @@ def rmsnorm(x, scale):
     pad = (-T) % br
     if pad:
         x2 = jnp.pad(x2, ((0, pad), (0, 0)))
-    out = rmsnorm_fwd(x2, scale, block_rows=br, interpret=not _on_tpu())
+    out = rmsnorm_fwd(x2, scale, block_rows=br, interpret=interpret)
     return out[:T].reshape(shape)
 
 
-@jax.jit
-def rmsnorm_residual(x, res, scale):
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def rmsnorm_residual(x, res, scale, *, interpret: bool = False):
+    check_backend(interpret)
     shape = x.shape
     x2 = x.reshape(-1, shape[-1])
     r2 = res.reshape(-1, shape[-1])
@@ -37,5 +39,5 @@ def rmsnorm_residual(x, res, scale):
     if pad:
         x2 = jnp.pad(x2, ((0, pad), (0, 0)))
         r2 = jnp.pad(r2, ((0, pad), (0, 0)))
-    y, r = rmsnorm_residual_fwd(x2, r2, scale, block_rows=br, interpret=not _on_tpu())
+    y, r = rmsnorm_residual_fwd(x2, r2, scale, block_rows=br, interpret=interpret)
     return y[:T].reshape(shape), r[:T].reshape(shape)

@@ -21,21 +21,23 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    """jax.make_mesh when it uses all devices; explicit device subset otherwise
-    (the dry-run process exposes 512 host devices; the single-pod mesh uses the
-    first 256)."""
+    """Mesh over the first prod(shape) devices, every axis `Auto`.
+
+    The model relies on GSPMD propagation (gathers, reshapes under sharding
+    constraints); `Explicit` axes, `jax.make_mesh`'s default since JAX 0.9,
+    reject those ops. The dry-run process exposes 512 host devices and the
+    single-pod mesh uses the first 256.
+    """
     import math
 
-    import numpy as np
-    from jax.sharding import Mesh
+    from jax.sharding import AxisType
 
     n = math.prod(shape)
     devices = jax.devices()
-    if n == len(devices):
-        return jax.make_mesh(shape, axes)
     if n > len(devices):
         raise ValueError(f"mesh {shape} needs {n} devices, have {len(devices)}")
-    return Mesh(np.array(devices[:n]).reshape(shape), axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices[:n])
 
 
 def rules_for(mesh, shape: ShapeSpec | None = None, *, sequence_parallel: bool = True,

@@ -1,32 +1,39 @@
 """Training launcher.
 
+  PYTHONPATH=src python -m repro.launch.train --arch starcoder2-3b --layers 4 \
+      --batch 2 --seq 4096 --steps 5
   PYTHONPATH=src python -m repro.launch.train --arch qwen3-14b --reduced \
       --steps 50 --checkpoint-dir /tmp/ckpt
   PYTHONPATH=src python -m repro.launch.train --arch gpt-175b --auto-plan \
       --chips 1024 --batch 1024   # analytic planning only (no execution)
 
-On this CPU container, execution uses `--reduced` configs; full configs are
-exercised via `repro.launch.dryrun` (AOT lower+compile) and `--auto-plan`
-(the paper's analytical planner).
+Trains the published config, with random initial weights and synthetic data,
+unless `--reduced` asks for the tiny same-family config. `--layers N` keeps
+every width, head count and the vocabulary as published and cuts only the
+depth, to what one chip holds. `--mesh 2x2` shards over a (data, model) mesh.
 """
 
 from __future__ import annotations
 
 import argparse
-
+import dataclasses
 
 from repro.configs import get_config
 from repro.configs.base import ParallelConfig, TrainConfig
 from repro.data.pipeline import Prefetcher, SyntheticLM
+from repro.launch.runtime import device_info, enable_compile_cache
 from repro.models.transformer import Model
 from repro.train.trainer import Trainer
 
 
-def main():
-    ap = argparse.ArgumentParser()
+def build_parser() -> argparse.ArgumentParser:
+    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--arch", required=True)
     ap.add_argument("--steps", type=int, default=50)
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action="store_true",
+                    help="tiny same-family config instead of the published one")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="cut the depth to N layers, every width as published")
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--microbatches", type=int, default=1)
@@ -41,7 +48,12 @@ def main():
     ap.add_argument("--auto-plan", action="store_true")
     ap.add_argument("--chips", type=int, default=256)
     ap.add_argument("--hardware", default="tpu-v5e")
-    args = ap.parse_args()
+    return ap
+
+
+def main(argv: list[str] | None = None):
+    """Train; returns (final state, per-step history), or None for --auto-plan."""
+    args = build_parser().parse_args(argv)
 
     if args.auto_plan:
         from repro.core.hardware import get_hardware
@@ -55,12 +67,17 @@ def main():
         for p in plan(cfg, hw, args.chips, global_batch=args.batch, seq=args.seq,
                       max_tp=64):
             print(" ", p.describe())
-        return
+        return None
 
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
+    if args.layers:
+        cfg = dataclasses.replace(cfg, num_layers=args.layers)
     model = Model(cfg)
+    print(f"{cfg.name} ({cfg.num_layers} layers, {model.param_count() / 1e9:.2f}B params"
+          f"{', reduced' if args.reduced else ''}) on {device_info()}"
+          + (f", mesh {args.mesh}" if args.mesh else ""))
 
     mesh = rules = None
     pcfg = ParallelConfig(remat=args.remat, microbatches=args.microbatches,
@@ -96,7 +113,9 @@ def main():
     state, history = trainer.fit(state, data, steps=args.steps - start, start_step=start)
     print(f"done: final loss {history[-1]['loss']:.4f}, "
           f"straggler steps {history[-1]['slow_steps']}")
+    return state, history
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

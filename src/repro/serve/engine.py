@@ -14,6 +14,7 @@ adequate for the near-equal-length request mixes the benchmarks use.
 
 from __future__ import annotations
 
+import time
 from dataclasses import dataclass, field
 
 import jax
@@ -30,6 +31,9 @@ class Request:
     temperature: float = 0.0
     out_tokens: list[int] = field(default_factory=list)
     done: bool = False
+    # (V,) logits the last out token was chosen from: with greedy sampling
+    # these are the model's logits at prompt + out_tokens[:-1]
+    last_logits: np.ndarray | None = None
 
 
 class ServeEngine:
@@ -39,7 +43,12 @@ class ServeEngine:
         self.max_len = max_len
         self.slots = slots
         self.key = jax.random.PRNGKey(seed)
-        self.decode_steps = 0  # decode iterations of the last serve() call
+        # of the last serve() call: decode iterations, and host seconds spent
+        # in prefill (with the cache splice) and in decode, each up to the
+        # logits' arrival on the host
+        self.decode_steps = 0
+        self.prefill_s = 0.0
+        self.decode_s = 0.0
 
         self._prefill = jax.jit(lambda p, b: model.prefill(p, b, max_len=max_len))
         self._decode = jax.jit(
@@ -77,12 +86,13 @@ class ServeEngine:
     def serve(self, requests: list[Request], *, eos: int | None = None) -> list[Request]:
         """Continuous-batching-lite scheduler over a fixed slot count.
 
-        `self.decode_steps` reports the decode iterations of the last call."""
+        `decode_steps`, `prefill_s` and `decode_s` describe the last call."""
         pending = list(requests)
         active: list[Request | None] = [None] * self.slots
         cache = None
         logits_np = None
         self.decode_steps = 0
+        self.prefill_s = self.decode_s = 0.0
         while pending or any(a is not None for a in active):
             # fill free slots: batch-prefill all newly admitted requests
             admit = []
@@ -91,7 +101,9 @@ class ServeEngine:
                     active[s] = pending.pop(0)
                     admit.append(s)
             if admit:
+                t0 = time.perf_counter()
                 cache, logits_np = self._admit(admit, active, cache, logits_np)
+                self.prefill_s += time.perf_counter() - t0
             live = [s for s in range(self.slots) if active[s] is not None]
             if not live:
                 break
@@ -103,13 +115,16 @@ class ServeEngine:
                 nxt[s] = tok
                 if (eos is not None and tok == eos) or len(r.out_tokens) >= r.max_new_tokens:
                     r.done = True
+                    r.last_logits = logits_np[s].copy()
                     active[s] = None
             if not any(active[s] is not None for s in live):
                 # every live slot finished this step: the decode would only
                 # produce logits for freed slots (stale by the next admit)
                 continue
+            t0 = time.perf_counter()
             logits, cache = self._decode(self.params, cache, jnp.asarray(nxt)[:, None])
             logits_np = np.array(logits)
+            self.decode_s += time.perf_counter() - t0
             self.decode_steps += 1
         return requests
 

@@ -102,12 +102,15 @@ class Trainer:
     def init_state(self, seed: int | None = None) -> dict:
         with self._ctx():
             key = jax.random.PRNGKey(self.tcfg.seed if seed is None else seed)
-            params = self.model.init(key)
-            if self._state_shardings is not None:
-                params = jax.device_put(params, self._state_shardings["params"])
-            opt = adamw_init(params, self.tcfg)
-            if self._state_shardings is not None:
-                opt = jax.device_put(opt, self._state_shardings["opt"])
+            if self._state_shardings is None:
+                params = self.model.init(key)
+                opt = adamw_init(params, self.tcfg)
+            else:
+                # born sharded: never whole on one device
+                params = jax.jit(self.model.init,
+                                 out_shardings=self._state_shardings["params"])(key)
+                opt = jax.jit(lambda p: adamw_init(p, self.tcfg),
+                              out_shardings=self._state_shardings["opt"])(params)
             state = {"params": params, "opt": opt}
             if self.pcfg.grad_compress:
                 err = jax.tree.map(
