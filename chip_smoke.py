@@ -37,17 +37,19 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), "src
 # decode) and a full forward pass. The two paths compute the same function in
 # the config's bfloat16 with different operation orders (chunked vs. cached
 # attention, different fusions), so they differ by bf16 rounding accumulated
-# over the layers: 1.1e-2 at 8 layers and d_model 512 on the CPU, more over
-# 30 layers. One step down in precision (fp8 e4m3, unit roundoff 2**-4 =
-# 6.25e-2 per operation) would exceed it.
+# over the layers: 1.1e-2 at 8 layers and d_model 512 on the CPU, 1.5e-2 to
+# 1.7e-2 at 30 layers on a TPU v5e. A wrong answer lands far above it: the
+# same logits are 0.87-0.91 away from another request's reference. We expect,
+# untested, that one step down in precision (fp8 e4m3, unit roundoff 6.25e-2
+# per operation) would exceed it too.
 LOGITS_RTOL = 5e-2
 # Per-step loss difference allowed between the 2x2 mesh and one chip. The
-# sharded program reduces partial sums in another order in bf16, which moves
-# the first loss by ~1e-3; later steps add bf16 rounding of each update, and
-# at full width the first Adam steps swing the loss by several nats
-# (11.3 -> 8.5 -> 16.0 on one chip), which amplifies such differences. A
-# wrong sharding (a missing reduction, half the batch per gradient) moves the
-# loss by tenths or more.
+# sharded program reduces partial sums in another order in bf16: 1.5e-4 to
+# 3.8e-4 over 3 steps on a TPU v5e, 1e-4 at d_model 512 on 4 CPU devices.
+# At full width the first Adam steps swing the loss by several nats
+# (11.3 -> 8.5 -> 16.0 on one chip), which could amplify such differences,
+# hence the margin. We expect, untested, that a wrong sharding (a missing
+# reduction, half the batch per gradient) moves the loss by tenths or more.
 LOSS_ATOL = 2e-2
 
 SERVE = ["--arch", "starcoder2-3b", "--slots", "8", "--requests", "8",
@@ -93,6 +95,26 @@ def peak_bytes() -> int | None:
     return (jax.devices()[0].memory_stats() or {}).get("peak_bytes_in_use")
 
 
+def recording_engine():
+    """ServeEngine that keeps its last decode step's logits. When every
+    request finishes on the same step, as in one admission wave of equal
+    lengths, slot i's row holds the logits request i's last token came from."""
+    from repro.serve.engine import ServeEngine
+
+    class RecordingEngine(ServeEngine):
+        def __init__(self, *args, **kwargs):
+            super().__init__(*args, **kwargs)
+            decode = self._decode
+
+            def record(params, cache, tokens):
+                self.last_decode_logits, cache = decode(params, cache, tokens)
+                return self.last_decode_logits, cache
+
+            self._decode = record
+
+    return RecordingEngine
+
+
 def reference_logits(engine, done):
     """Full forward pass over prompt + out_tokens[:-1], logits at the last one."""
     import jax
@@ -116,9 +138,16 @@ def serve_phase(reduced: bool, clock: CompileClock) -> None:
     from repro.launch import serve
 
     c0, t0 = clock.seconds, time.perf_counter()
-    engine, done = serve.main(SERVE_REDUCED if reduced else SERVE)
+    plain, serve.ServeEngine = serve.ServeEngine, recording_engine()
+    try:
+        engine, done = serve.main(SERVE_REDUCED if reduced else SERVE)
+    finally:
+        serve.ServeEngine = plain
     wall = time.perf_counter() - t0
-    got = np.stack([r.last_logits for r in done])
+    got = np.asarray(engine.last_decode_logits)[: len(done)]
+    # greedy: each last token is the argmax of the row it was chosen from
+    if [r.out_tokens[-1] for r in done] != got.argmax(-1).tolist():
+        raise AssertionError("recorded logits are not those the last tokens came from")
     compile_s = clock.seconds - c0
     decode_tokens = sum(len(r.out_tokens) - 1 for r in done)
     fact("serve", compile_s=compile_s, phase_wall_s=wall, prefill_s=engine.prefill_s,

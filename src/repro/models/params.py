@@ -62,7 +62,8 @@ def _materialize(pd: PD, key, default_dtype) -> jax.Array:
     if pd.init == "constant":
         return jnp.full(pd.shape, pd.constant, dtype)
     # random leaves are drawn as a matrix and reshaped: the same values, and
-    # the TPU compiler takes several times longer over a 3-D layer-stack draw
+    # for a described TPU v5e starcoder2-3b's 7 random leaves compile in
+    # 31.5-32.9 s this way against 53.9-56.1 s drawn at their 3-D stack shape
     mat = (math.prod(pd.shape[:-1]), pd.shape[-1]) if len(pd.shape) > 2 else pd.shape
     if pd.init == "normal":
         x = jax.random.normal(key, mat, jnp.float32) * pd.stddev

@@ -31,9 +31,6 @@ class Request:
     temperature: float = 0.0
     out_tokens: list[int] = field(default_factory=list)
     done: bool = False
-    # (V,) logits the last out token was chosen from: with greedy sampling
-    # these are the model's logits at prompt + out_tokens[:-1]
-    last_logits: np.ndarray | None = None
 
 
 class ServeEngine:
@@ -115,7 +112,6 @@ class ServeEngine:
                 nxt[s] = tok
                 if (eos is not None and tok == eos) or len(r.out_tokens) >= r.max_new_tokens:
                     r.done = True
-                    r.last_logits = logits_np[s].copy()
                     active[s] = None
             if not any(active[s] is not None for s in live):
                 # every live slot finished this step: the decode would only

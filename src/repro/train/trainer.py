@@ -102,15 +102,13 @@ class Trainer:
     def init_state(self, seed: int | None = None) -> dict:
         with self._ctx():
             key = jax.random.PRNGKey(self.tcfg.seed if seed is None else seed)
-            if self._state_shardings is None:
-                params = self.model.init(key)
-                opt = adamw_init(params, self.tcfg)
-            else:
-                # born sharded: never whole on one device
-                params = jax.jit(self.model.init,
-                                 out_shardings=self._state_shardings["params"])(key)
-                opt = jax.jit(lambda p: adamw_init(p, self.tcfg),
-                              out_shardings=self._state_shardings["opt"])(params)
+            sh = self._state_shardings
+
+            def placed(part):  # on a mesh, made sharded: never whole on one device
+                return {} if sh is None else {"out_shardings": sh[part]}
+
+            params = jax.jit(self.model.init, **placed("params"))(key)
+            opt = jax.jit(lambda p: adamw_init(p, self.tcfg), **placed("opt"))(params)
             state = {"params": params, "opt": opt}
             if self.pcfg.grad_compress:
                 err = jax.tree.map(

@@ -46,10 +46,6 @@ def test_serve_main_reports_engine_and_requests():
     assert jax.config.jax_compilation_cache_dir == before  # only __main__ sets it
     assert all(r.done and len(r.out_tokens) == 3 for r in done)
     assert engine.decode_steps == 2 and engine.prefill_s > 0 and engine.decode_s > 0
-    vocab = engine.model.cfg.vocab_size
-    assert all(r.last_logits.shape == (vocab,) for r in done)
-    # greedy: the last token is the argmax of the logits it was chosen from
-    assert all(r.out_tokens[-1] == int(r.last_logits.argmax()) for r in done)
 
 
 def test_train_layers_cuts_depth_keeps_widths():
@@ -81,10 +77,13 @@ def test_chip_smoke_refuses_without_tpu(capsys):
 def test_chip_smoke_reduced_checks_pass_without_ok_line(capsys):
     if jax.devices()[0].platform == "tpu":
         pytest.skip("off-TPU the reduced run must not print ok")
+    from repro.serve.engine import ServeEngine
+
     assert _chip_smoke().main(["--reduced"]) != 0
     out, _ = capsys.readouterr()
     assert "serve check:" in out and "\ntrain:" in out
     assert '"ok"' not in out
+    assert serve.ServeEngine is ServeEngine  # the smoke's recording engine is undone
 
 
 def test_chip_smoke_four_chips_on_virtual_devices():

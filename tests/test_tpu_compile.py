@@ -11,6 +11,7 @@ at a time may load the TPU library, and every test worker imports this file.
 
 import dataclasses
 import functools
+import importlib.util
 
 import pytest
 
@@ -36,6 +37,9 @@ SLOTS, PROMPT, MAX_LEN = 8, 1024, 2048
 
 @pytest.fixture(scope="module")
 def one_chip():
+    # only a missing TPU compiler skips; any error describing the chip fails
+    if importlib.util.find_spec("libtpu") is None:
+        pytest.skip("no TPU compiler (libtpu) is installed")
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
 
@@ -46,10 +50,7 @@ def one_chip():
     compilation_cache.reset_cache()
     with pytest.MonkeyPatch.context() as mp:
         mp.setenv("TPU_LOG_DIR", "disabled")
-        try:
-            topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
-        except Exception as e:  # no TPU compiler in this installation
-            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
         yield SingleDeviceSharding(topo.devices[0])
     jax.config.update("jax_enable_compilation_cache", was_on)
 
