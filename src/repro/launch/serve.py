@@ -69,7 +69,8 @@ def main(argv: list[str] | None = None) -> tuple[ServeEngine, list[Request]]:
     print(f"warm-up (compile, one prefill, one decode) {warm:.2f}s")
     print(f"served {len(done)} requests, {n_tok} tokens in {dt:.2f}s: "
           f"prefill {engine.prefill_s:.3f}s, {engine.decode_steps} decode steps "
-          f"in {engine.decode_s:.3f}s")
+          f"in {engine.decode_s:.3f}s; host: sample {engine.sample_s:.3f}s, "
+          f"dispatch {engine.dispatch_s:.3f}s")
     return engine, done
 
 

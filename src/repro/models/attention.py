@@ -10,6 +10,12 @@ real TPU target, and this path doubles as its distributed wrapper/reference.
 
 Supports: GQA (Hq = G * Hkv), RoPE, qk-RMSNorm (qwen3), sliding-window (danube),
 KV-cache prefill/decode with ring-buffer caches for SWA.
+
+Named scopes, stable because the chip benchmark's device-time metrics read
+them from the compiled programs' metadata: `attn`, holding `qkv` (projections,
+qk-norm and RoPE), `kv_write` (the cache build in prefill, the one-position
+update in decode), `core` (scores, mask, softmax and the product with V) and
+`out` (the `wo` projection).
 """
 
 from __future__ import annotations
@@ -43,25 +49,26 @@ def attn_defs(cfg: ModelConfig, d_in: int | None = None) -> dict:
 
 def _project_qkv(cfg: ModelConfig, p: dict, x: jax.Array, positions: jax.Array):
     """x: (B, S, D) -> q (B,S,Hkv,G,dh), k/v (B,S,Hkv,dh), RoPE'd + qk-normed."""
-    B, S, _ = x.shape
-    Hq, Hkv, dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    G = Hq // Hkv
-    q = (x @ p["wq"].astype(x.dtype)).reshape(B, S, Hq, dh)
-    k = (x @ p["wk"].astype(x.dtype)).reshape(B, S, Hkv, dh)
-    v = (x @ p["wv"].astype(x.dtype)).reshape(B, S, Hkv, dh)
-    if cfg.qk_norm:
-        q = rms_head_norm(p["q_norm"], q)
-        k = rms_head_norm(p["k_norm"], k)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
-    q = q.reshape(B, S, Hkv, G, dh)
-    # Heads shard over tp; seq stays unsharded here (Megatron SP applies only to
-    # the norm/residual regions — sharding seq over the same mesh axis as heads
-    # would be an illegal double use of the axis).
-    q = shard(q, "dp", None, "tp", None, None)
-    k = shard(k, "dp", None, "tp", None)
-    v = shard(v, "dp", None, "tp", None)
-    return q, k, v
+    with jax.named_scope("qkv"):
+        B, S, _ = x.shape
+        Hq, Hkv, dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+        G = Hq // Hkv
+        q = (x @ p["wq"].astype(x.dtype)).reshape(B, S, Hq, dh)
+        k = (x @ p["wk"].astype(x.dtype)).reshape(B, S, Hkv, dh)
+        v = (x @ p["wv"].astype(x.dtype)).reshape(B, S, Hkv, dh)
+        if cfg.qk_norm:
+            q = rms_head_norm(p["q_norm"], q)
+            k = rms_head_norm(p["k_norm"], k)
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k = apply_rope(k, positions, cfg.rope_theta)
+        q = q.reshape(B, S, Hkv, G, dh)
+        # Heads shard over tp; seq stays unsharded here (Megatron SP applies only to
+        # the norm/residual regions — sharding seq over the same mesh axis as heads
+        # would be an illegal double use of the axis).
+        q = shard(q, "dp", None, "tp", None, None)
+        k = shard(k, "dp", None, "tp", None)
+        v = shard(v, "dp", None, "tp", None)
+        return q, k, v
 
 
 # ------------------------------------------------------------- dense attention
@@ -146,25 +153,28 @@ def _chunked_attention(q, k, v, window, chunk, differentiable: bool = False):
 
 def self_attention(cfg: ModelConfig, p: dict, x: jax.Array, positions=None) -> jax.Array:
     """Full-sequence causal attention (training: differentiable paths only)."""
-    B, S, _ = x.shape
-    pos = jnp.arange(S) if positions is None else positions
-    q, k, v = _project_qkv(cfg, p, x, pos)
-    if cfg.attn_impl == "dense" or S <= cfg.attn_chunk:
-        out = _dense_attention(q, k, v, pos, pos, cfg.sliding_window)
-    elif cfg.attn_impl == "pallas":
-        from repro.kernels.flash_attention import ops as fa_ops
+    with jax.named_scope("attn"):
+        B, S, _ = x.shape
+        pos = jnp.arange(S) if positions is None else positions
+        q, k, v = _project_qkv(cfg, p, x, pos)
+        with jax.named_scope("core"):
+            if cfg.attn_impl == "dense" or S <= cfg.attn_chunk:
+                out = _dense_attention(q, k, v, pos, pos, cfg.sliding_window)
+            elif cfg.attn_impl == "pallas":
+                from repro.kernels.flash_attention import ops as fa_ops
 
-        out = fa_ops.flash_attention(q, k, v, window=cfg.sliding_window)
-    else:
-        # flash-attention custom VJP: O(S*d) residuals, scores recomputed in
-        # bwd — a scan-based differentiable path would store every (c, c)
-        # fp32 score block and blow HBM at 4k+ sequal lengths
-        from repro.models.flash_vjp import flash_attention_vjp
+                out = fa_ops.flash_attention(q, k, v, window=cfg.sliding_window)
+            else:
+                # flash-attention custom VJP: O(S*d) residuals, scores recomputed in
+                # bwd — a scan-based differentiable path would store every (c, c)
+                # fp32 score block and blow HBM at 4k+ sequal lengths
+                from repro.models.flash_vjp import flash_attention_vjp
 
-        out = flash_attention_vjp(q, k, v, cfg.sliding_window, cfg.attn_chunk)
-    out = out.reshape(B, S, cfg.num_heads * cfg.head_dim)
-    out = out @ p["wo"].astype(out.dtype)
-    return shard(out, "dp", "sp", None)
+                out = flash_attention_vjp(q, k, v, cfg.sliding_window, cfg.attn_chunk)
+        with jax.named_scope("out"):
+            out = out.reshape(B, S, cfg.num_heads * cfg.head_dim)
+            out = out @ p["wo"].astype(out.dtype)
+            return shard(out, "dp", "sp", None)
 
 
 # ----------------------------------------------------------------- KV caching
@@ -213,33 +223,37 @@ def prefill_attention(cfg: ModelConfig, p: dict, x: jax.Array, max_len: int, cp:
 
     Token t lands in cache slot t (full) or t % W (ring buffer, SWA).
     """
-    B, S, _ = x.shape
-    pos = jnp.arange(S)
-    q, k, v = _project_qkv(cfg, p, x, pos)
-    if cfg.attn_impl == "dense" or S <= cfg.attn_chunk:
-        out = _dense_attention(q, k, v, pos, pos, cfg.sliding_window)
-    else:
-        out = _chunked_attention(q, k, v, cfg.sliding_window, cfg.attn_chunk)
-    out = out.reshape(B, S, cfg.num_heads * cfg.head_dim)
-    out = shard(out @ p["wo"].astype(out.dtype), "dp", None, None)
+    with jax.named_scope("attn"):
+        B, S, _ = x.shape
+        pos = jnp.arange(S)
+        q, k, v = _project_qkv(cfg, p, x, pos)
+        with jax.named_scope("core"):
+            if cfg.attn_impl == "dense" or S <= cfg.attn_chunk:
+                out = _dense_attention(q, k, v, pos, pos, cfg.sliding_window)
+            else:
+                out = _chunked_attention(q, k, v, cfg.sliding_window, cfg.attn_chunk)
+        with jax.named_scope("out"):
+            out = out.reshape(B, S, cfg.num_heads * cfg.head_dim)
+            out = shard(out @ p["wo"].astype(out.dtype), "dp", None, None)
 
-    W = cache_len(cfg, max_len)
-    cache = init_attn_cache(cfg, B, max_len, cp=cp)
-    if cfg.sliding_window is not None and S > W:
-        # keep last W tokens, permuted into ring order (slot = t mod W)
-        tail_t = jnp.arange(S - W, S)
-        ck = jnp.take(k, tail_t, axis=1)
-        cv = jnp.take(v, tail_t, axis=1)
-        slots = jnp.argsort(tail_t % W)
-        cache = {"k": jnp.take(ck, slots, axis=1), "v": jnp.take(cv, slots, axis=1)}
-    else:
-        cache = {
-            "k": jax.lax.dynamic_update_slice_in_dim(cache["k"], k, 0, axis=1),
-            "v": jax.lax.dynamic_update_slice_in_dim(cache["v"], v, 0, axis=1),
-        }
-    ax = cache_axes(cfg, cp)
-    cache = {kk: shard(vv, *ax) for kk, vv in cache.items()}
-    return out, cache
+        with jax.named_scope("kv_write"):
+            W = cache_len(cfg, max_len)
+            cache = init_attn_cache(cfg, B, max_len, cp=cp)
+            if cfg.sliding_window is not None and S > W:
+                # keep last W tokens, permuted into ring order (slot = t mod W)
+                tail_t = jnp.arange(S - W, S)
+                ck = jnp.take(k, tail_t, axis=1)
+                cv = jnp.take(v, tail_t, axis=1)
+                slots = jnp.argsort(tail_t % W)
+                cache = {"k": jnp.take(ck, slots, axis=1), "v": jnp.take(cv, slots, axis=1)}
+            else:
+                cache = {
+                    "k": jax.lax.dynamic_update_slice_in_dim(cache["k"], k, 0, axis=1),
+                    "v": jax.lax.dynamic_update_slice_in_dim(cache["v"], v, 0, axis=1),
+                }
+            ax = cache_axes(cfg, cp)
+            cache = {kk: shard(vv, *ax) for kk, vv in cache.items()}
+        return out, cache
 
 
 def decode_attention(cfg: ModelConfig, p: dict, x: jax.Array, cache: dict, pos: jax.Array, cp: bool = False):
@@ -248,35 +262,40 @@ def decode_attention(cfg: ModelConfig, p: dict, x: jax.Array, cache: dict, pos: 
     x: (B, 1, D); pos: scalar int32 = index of the current token (0-based).
     Returns (out (B,1,D), updated cache).
     """
-    B, _, _ = x.shape
-    Hq, Hkv, dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    G = Hq // Hkv
-    positions = jnp.full((B, 1), pos, jnp.int32)
-    q, k, v = _project_qkv(cfg, p, x, positions)
+    with jax.named_scope("attn"):
+        B, _, _ = x.shape
+        Hq, Hkv, dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+        G = Hq // Hkv
+        positions = jnp.full((B, 1), pos, jnp.int32)
+        q, k, v = _project_qkv(cfg, p, x, positions)
 
-    K, V = cache["k"], cache["v"]
-    W = K.shape[1]
-    write = pos % W if cfg.sliding_window is not None else pos
-    K = jax.lax.dynamic_update_slice(K, k, (0, write, 0, 0))
-    V = jax.lax.dynamic_update_slice(V, v, (0, write, 0, 0))
-    ax = cache_axes(cfg, cp)
-    K = shard(K, *ax)
-    V = shard(V, *ax)
+        K, V = cache["k"], cache["v"]
+        W = K.shape[1]
+        with jax.named_scope("kv_write"):
+            write = pos % W if cfg.sliding_window is not None else pos
+            K = jax.lax.dynamic_update_slice(K, k, (0, write, 0, 0))
+            V = jax.lax.dynamic_update_slice(V, v, (0, write, 0, 0))
+            ax = cache_axes(cfg, cp)
+            K = shard(K, *ax)
+            V = shard(V, *ax)
 
-    slot = jnp.arange(W)
-    if cfg.sliding_window is not None:
-        # slot i holds token t = pos - ((pos - i) mod W); valid iff t >= 0
-        t = pos - jnp.mod(pos - slot, W)
-        valid = t >= 0
-    else:
-        valid = slot <= pos
+        with jax.named_scope("core"):
+            slot = jnp.arange(W)
+            if cfg.sliding_window is not None:
+                # slot i holds token t = pos - ((pos - i) mod W); valid iff t >= 0
+                t = pos - jnp.mod(pos - slot, W)
+                valid = t >= 0
+            else:
+                valid = slot <= pos
 
-    scale = dh**-0.5
-    s = jnp.einsum("bqhgd,bkhd->bhgqk", q, K, preferred_element_type=jnp.float32) * scale
-    s = jnp.where(valid[None, None, None, None, :], s, NEG_INF)
-    # softmax over a (possibly context-parallel-sharded) axis: GSPMD inserts the
-    # flash-decode-style partial max/sum all-reduces automatically.
-    pr = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bhgqk,bkhd->bqhgd", pr.astype(V.dtype), V)
-    out = out.reshape(B, 1, Hq * dh) @ p["wo"].astype(x.dtype)
-    return shard(out, "dp", None, None), {"k": K, "v": V}
+            scale = dh**-0.5
+            s = jnp.einsum("bqhgd,bkhd->bhgqk", q, K, preferred_element_type=jnp.float32) * scale
+            s = jnp.where(valid[None, None, None, None, :], s, NEG_INF)
+            # softmax over a (possibly context-parallel-sharded) axis: GSPMD inserts the
+            # flash-decode-style partial max/sum all-reduces automatically.
+            pr = jax.nn.softmax(s, axis=-1)
+            out = jnp.einsum("bhgqk,bkhd->bqhgd", pr.astype(V.dtype), V)
+        with jax.named_scope("out"):
+            out = out.reshape(B, 1, Hq * dh) @ p["wo"].astype(x.dtype)
+            out = shard(out, "dp", None, None)
+        return out, {"k": K, "v": V}

@@ -34,16 +34,17 @@ def norm_defs(d: int, kind: str) -> dict:
 
 def apply_norm(p: dict, x: jax.Array, eps: float = 1e-5) -> jax.Array:
     """RMSNorm / LayerNorm in fp32, cast back to input dtype."""
-    dtype = x.dtype
-    xf = x.astype(jnp.float32)
-    if "bias" in p:  # layernorm
-        mu = jnp.mean(xf, axis=-1, keepdims=True)
-        var = jnp.var(xf, axis=-1, keepdims=True)
-        y = (xf - mu) * jax.lax.rsqrt(var + eps) * p["scale"] + p["bias"]
-    else:  # rmsnorm
-        ms = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
-        y = xf * jax.lax.rsqrt(ms + eps) * p["scale"]
-    return y.astype(dtype)
+    with jax.named_scope("norm"):
+        dtype = x.dtype
+        xf = x.astype(jnp.float32)
+        if "bias" in p:  # layernorm
+            mu = jnp.mean(xf, axis=-1, keepdims=True)
+            var = jnp.var(xf, axis=-1, keepdims=True)
+            y = (xf - mu) * jax.lax.rsqrt(var + eps) * p["scale"] + p["bias"]
+        else:  # rmsnorm
+            ms = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+            y = xf * jax.lax.rsqrt(ms + eps) * p["scale"]
+        return y.astype(dtype)
 
 
 def rms_head_norm(scale: jax.Array, x: jax.Array, eps: float = 1e-6) -> jax.Array:
@@ -98,9 +99,10 @@ def embed_defs(vocab: int, d: int) -> dict:
 
 
 def embed_lookup(p: dict, tokens: jax.Array, dtype) -> jax.Array:
-    emb = p["tok"].astype(dtype)
-    x = jnp.take(emb, tokens, axis=0)
-    return shard(x, "dp", "sp", None)
+    with jax.named_scope("embed"):
+        emb = p["tok"].astype(dtype)
+        x = jnp.take(emb, tokens, axis=0)
+        return shard(x, "dp", "sp", None)
 
 
 def head_defs(d: int, vocab: int) -> dict:

@@ -22,15 +22,16 @@ def mlp_defs(d: int, d_ff: int, gated: bool) -> dict:
 
 def apply_mlp(p: dict, x: jax.Array, act_name: str) -> jax.Array:
     """(B, S, D) or (T, D) -> same rank. One logical all-reduce after wo."""
-    act = activation(act_name)
-    dt = x.dtype
-    h = x @ p["wi"].astype(dt)
-    if "wg" in p:
-        h = act(x @ p["wg"].astype(dt)) * h
-    else:
-        h = act(h)
-    if x.ndim == 3:
-        h = shard(h, "dp", None, "tp")
-        return shard(h @ p["wo"].astype(dt), "dp", "sp", None)
-    h = shard(h, "dp", "tp")
-    return shard(h @ p["wo"].astype(dt), "dp", None)
+    with jax.named_scope("mlp"):
+        act = activation(act_name)
+        dt = x.dtype
+        h = x @ p["wi"].astype(dt)
+        if "wg" in p:
+            h = act(x @ p["wg"].astype(dt)) * h
+        else:
+            h = act(h)
+        if x.ndim == 3:
+            h = shard(h, "dp", None, "tp")
+            return shard(h @ p["wo"].astype(dt), "dp", "sp", None)
+        h = shard(h, "dp", "tp")
+        return shard(h @ p["wo"].astype(dt), "dp", None)

@@ -11,6 +11,11 @@ Families:
 
 Execution modes: train/forward (no cache), prefill (returns decode cache),
 decode (one token, O(1) state/KV updates).
+
+Named scopes mark each part of the step in the compiled programs' metadata,
+where the chip benchmark reads device time by part; later changes keep the
+names: `embed`, `norm`, `attn` (with its children, see `attention.py`), `mlp`
+and `head`.
 """
 
 from __future__ import annotations
@@ -242,10 +247,11 @@ class Model:
         return shard(x, "dp", "sp", None)
 
     def _head(self, params, x) -> jax.Array:
-        p = params.get("lm_head")
-        if p is None:  # tied
-            p = {"w": params["embed"]["tok"].T}
-        return lm_logits(p, x, jnp.float32)
+        with jax.named_scope("head"):
+            p = params.get("lm_head")
+            if p is None:  # tied
+                p = {"w": params["embed"]["tok"].T}
+            return lm_logits(p, x, jnp.float32)
 
     # ---------------------------------------------------------------- forward
     def forward(self, params, batch, remat: str | None = None):
