@@ -185,7 +185,12 @@ def cache_len(cfg: ModelConfig, max_len: int) -> int:
 
 
 def cache_axes(cfg: ModelConfig, cp: bool = False) -> tuple:
-    """Logical axes for a (B, W, Hkv, dh) KV cache under the current mesh.
+    """Logical axes for a (W, Hkv, B, dh) KV cache under the current mesh.
+
+    The cache is stored in the order the TPU keeps it in while decoding
+    (position, KV head, batch, head dim), so that the decode program updates
+    the donated buffer in place; in (B, W, Hkv, dh) order XLA relays the whole
+    cache out on entry and back on exit of every step.
 
     KV heads shard over tp when they divide evenly; otherwise the tp axes move
     to the cache-length dim (sequence-sharded decode attention — GSPMD turns
@@ -198,15 +203,15 @@ def cache_axes(cfg: ModelConfig, cp: bool = False) -> tuple:
     tp = axes_size("tp")
     heads_shardable = tp > 1 and cfg.num_kv_heads % tp == 0
     if heads_shardable:
-        return ("dp", "cp" if cp else None, "tp", None)
+        return ("cp" if cp else None, "tp", "dp", None)
     seq = ("cp", "tp") if cp else "tp"
-    return ("dp", seq, None, None)
+    return (seq, None, "dp", None)
 
 
 def init_attn_cache(cfg: ModelConfig, batch: int, max_len: int, cp: bool = False) -> dict:
-    """Zeroed KV cache, sharded per cache_axes."""
+    """Zeroed (W, Hkv, B, dh) KV cache, sharded per cache_axes."""
     W = cache_len(cfg, max_len)
-    shp = (batch, W, cfg.num_kv_heads, cfg.head_dim)
+    shp = (W, cfg.num_kv_heads, batch, cfg.head_dim)
     ax = cache_axes(cfg, cp)
     k = shard(jnp.zeros(shp, cfg.compute_dtype), *ax)
     v = shard(jnp.zeros(shp, cfg.compute_dtype), *ax)
@@ -238,48 +243,60 @@ def prefill_attention(cfg: ModelConfig, p: dict, x: jax.Array, max_len: int, cp:
 
         with jax.named_scope("kv_write"):
             W = cache_len(cfg, max_len)
-            cache = init_attn_cache(cfg, B, max_len, cp=cp)
+            # (B, S, Hkv, dh) -> the cache's (S, Hkv, B, dh)
+            k, v = (jnp.transpose(a, (1, 2, 0, 3)) for a in (k, v))
             if cfg.sliding_window is not None and S > W:
                 # keep last W tokens, permuted into ring order (slot = t mod W)
                 tail_t = jnp.arange(S - W, S)
-                ck = jnp.take(k, tail_t, axis=1)
-                cv = jnp.take(v, tail_t, axis=1)
-                slots = jnp.argsort(tail_t % W)
-                cache = {"k": jnp.take(ck, slots, axis=1), "v": jnp.take(cv, slots, axis=1)}
+                slots = tail_t[jnp.argsort(tail_t % W)]
+                cache = {"k": jnp.take(k, slots, axis=0), "v": jnp.take(v, slots, axis=0)}
             else:
+                cache = init_attn_cache(cfg, B, max_len, cp=cp)
                 cache = {
-                    "k": jax.lax.dynamic_update_slice_in_dim(cache["k"], k, 0, axis=1),
-                    "v": jax.lax.dynamic_update_slice_in_dim(cache["v"], v, 0, axis=1),
+                    "k": jax.lax.dynamic_update_slice_in_dim(cache["k"], k, 0, axis=0),
+                    "v": jax.lax.dynamic_update_slice_in_dim(cache["v"], v, 0, axis=0),
                 }
             ax = cache_axes(cfg, cp)
             cache = {kk: shard(vv, *ax) for kk, vv in cache.items()}
         return out, cache
 
 
-def decode_attention(cfg: ModelConfig, p: dict, x: jax.Array, cache: dict, pos: jax.Array, cp: bool = False):
+def decode_attention(cfg: ModelConfig, p: dict, x: jax.Array, cache: dict, pos: jax.Array,
+                     cp: bool = False, layer: jax.Array | None = None):
     """One-token decode: q over the KV cache (the paper's skinny-GEMM regime).
 
     x: (B, 1, D); pos: scalar int32 = index of the current token (0-based).
-    Returns (out (B,1,D), updated cache).
+    `cache` holds one layer's (W, Hkv, B, dh) K and V, or, with `layer` given,
+    the whole stack's (L, W, Hkv, B, dh): the new position is written into
+    that layer of the stacked buffer, which is then read in place. Returns
+    (out (B,1,D), updated cache, in the form it was given).
     """
     with jax.named_scope("attn"):
         B, _, _ = x.shape
         Hq, Hkv, dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-        G = Hq // Hkv
         positions = jnp.full((B, 1), pos, jnp.int32)
         q, k, v = _project_qkv(cfg, p, x, positions)
 
         K, V = cache["k"], cache["v"]
-        W = K.shape[1]
+        W = K.shape[-4]
         with jax.named_scope("kv_write"):
             write = pos % W if cfg.sliding_window is not None else pos
-            K = jax.lax.dynamic_update_slice(K, k, (0, write, 0, 0))
-            V = jax.lax.dynamic_update_slice(V, v, (0, write, 0, 0))
+            # (B, 1, Hkv, dh) -> the cache's (1, Hkv, B, dh)
+            k, v = (jnp.transpose(a, (1, 2, 0, 3)) for a in (k, v))
             ax = cache_axes(cfg, cp)
-            K = shard(K, *ax)
-            V = shard(V, *ax)
+            at = (write, 0, 0, 0)
+            if layer is not None:
+                k, v, ax, at = k[None], v[None], (None, *ax), (layer, *at)
+            # constrained as read and as written: as the layer scan's carry the
+            # cache would otherwise take the sharding the attention prefers
+            K = shard(jax.lax.dynamic_update_slice(shard(K, *ax), k, at), *ax)
+            V = shard(jax.lax.dynamic_update_slice(shard(V, *ax), v, at), *ax)
 
         with jax.named_scope("core"):
+            Kl, Vl = K, V
+            if layer is not None:
+                Kl = jax.lax.dynamic_index_in_dim(K, layer, axis=0, keepdims=False)
+                Vl = jax.lax.dynamic_index_in_dim(V, layer, axis=0, keepdims=False)
             slot = jnp.arange(W)
             if cfg.sliding_window is not None:
                 # slot i holds token t = pos - ((pos - i) mod W); valid iff t >= 0
@@ -289,12 +306,12 @@ def decode_attention(cfg: ModelConfig, p: dict, x: jax.Array, cache: dict, pos: 
                 valid = slot <= pos
 
             scale = dh**-0.5
-            s = jnp.einsum("bqhgd,bkhd->bhgqk", q, K, preferred_element_type=jnp.float32) * scale
+            s = jnp.einsum("bqhgd,khbd->bhgqk", q, Kl, preferred_element_type=jnp.float32) * scale
             s = jnp.where(valid[None, None, None, None, :], s, NEG_INF)
             # softmax over a (possibly context-parallel-sharded) axis: GSPMD inserts the
             # flash-decode-style partial max/sum all-reduces automatically.
             pr = jax.nn.softmax(s, axis=-1)
-            out = jnp.einsum("bhgqk,bkhd->bqhgd", pr.astype(V.dtype), V)
+            out = jnp.einsum("bhgqk,khbd->bqhgd", pr.astype(Vl.dtype), Vl)
         with jax.named_scope("out"):
             out = out.reshape(B, 1, Hq * dh) @ p["wo"].astype(x.dtype)
             out = shard(out, "dp", None, None)

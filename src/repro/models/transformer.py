@@ -87,7 +87,7 @@ def _shared_block_defs(cfg: ModelConfig) -> dict:
 
 
 # ----------------------------------------------------------------- layer apply
-def _apply_dense_layer(cfg, p, x, mode, cache=None, pos=None, max_len=0, cp=False):
+def _apply_dense_layer(cfg, p, x, mode, cache=None, pos=None, max_len=0, cp=False, layer=None):
     h = apply_norm(p["ln1"], x)
     new_cache: dict[str, Any] = {}
     if mode == "train":
@@ -95,8 +95,8 @@ def _apply_dense_layer(cfg, p, x, mode, cache=None, pos=None, max_len=0, cp=Fals
     elif mode == "prefill":
         a, kv = attn.prefill_attention(cfg, p["attn"], h, max_len, cp=cp)
         new_cache["kv"] = kv
-    else:  # decode
-        a, kv = attn.decode_attention(cfg, p["attn"], h, cache["kv"], pos, cp=cp)
+    else:  # decode; with `layer`, cache["kv"] is the whole stack's
+        a, kv = attn.decode_attention(cfg, p["attn"], h, cache["kv"], pos, cp=cp, layer=layer)
         new_cache["kv"] = kv
     x = x + a
     h = apply_norm(p["ln2"], x)
@@ -504,15 +504,29 @@ class Model:
                 )
                 new_head[str(i)] = c
 
-            def body(x, inp):
-                lp, lc = inp
-                if self.is_rwkv:
-                    x, st = _apply_rwkv_layer(cfg, lp, x, "decode", cache=lc)
-                else:
-                    x, st, _ = _apply_dense_layer(cfg, lp, x, "decode", cache=lc, pos=pos, cp=cp)
-                return x, st
+            if self.is_rwkv:
 
-            x, scan_caches = jax.lax.scan(body, x, (params["layers"], cache["layers"]))
+                def body(x, inp):
+                    lp, lc = inp
+                    return _apply_rwkv_layer(cfg, lp, x, "decode", cache=lc)
+
+                x, scan_caches = jax.lax.scan(body, x, (params["layers"], cache["layers"]))
+            else:
+                # the stacked KV cache rides in the carry and each layer writes
+                # its one position into it in place; as a scan input and output
+                # it would be sliced out and written back whole every layer
+
+                def body(carry, inp):
+                    x, kv = carry
+                    lp, i = inp
+                    x, c, _ = _apply_dense_layer(
+                        cfg, lp, x, "decode", cache={"kv": kv}, pos=pos, cp=cp, layer=i
+                    )
+                    return (x, c["kv"]), None
+
+                xs = (params["layers"], jnp.arange(self.n_scan()))
+                (x, kv), _ = jax.lax.scan(body, (x, cache["layers"]["kv"]), xs)
+                scan_caches = {"kv": kv}
             new_cache = {"layers": scan_caches}
             if new_head:
                 new_cache["head_layers"] = new_head
@@ -523,63 +537,65 @@ class Model:
         return logits, new_cache
 
     # ----------------------------------------------------- cache sharding spec
-    def cache_pspecs(self, cp: bool = False):
-        """PartitionSpec tree matching init_cache structure (for pjit shardings).
-
-        Leaves are PartitionSpec (resolved under the current sharding rules);
-        built by name-mapping the per-component logical spec dicts.
-        """
-        from repro.parallel.axes import logical_spec
-
-        def _is_axes(t) -> bool:
-            # a logical-axes tuple: entries are names, None, or tuples of names
-            return isinstance(t, tuple) and all(
-                isinstance(n, (str, type(None)))
-                or (isinstance(n, tuple) and all(isinstance(m, str) for m in n))
-                for n in t
-            )
-
-        def to_p(spec_tree):
-            return jax.tree.map(lambda names: logical_spec(*names), spec_tree, is_leaf=_is_axes)
-
+    def _cache_axes(self, cp: bool = False):
+        """Logical-axes tree matching init_cache structure; "dp" marks the
+        batch axis of every leaf but "pos"."""
         cfg = self.cfg
+        is_t = lambda t: isinstance(t, tuple)  # noqa: E731
         if self.is_hybrid:
             m = mamba2.mamba2_state_specs(cfg)
             a = attn.attn_cache_specs(cfg, cp=cp)
-            is_t = lambda t: isinstance(t, tuple)  # noqa: E731
             k = cfg.attn_every
             n_seg = cfg.num_layers // k
             n_tail = cfg.num_layers - n_seg * k
             cache = {}
             if n_seg:
                 cache["seg"] = {
-                    "shared": to_p(jax.tree.map(lambda t: (None, *t), a, is_leaf=is_t)),
-                    "mamba": to_p(jax.tree.map(lambda t: (None, None, *t), m, is_leaf=is_t)),
+                    "shared": jax.tree.map(lambda t: (None, *t), a, is_leaf=is_t),
+                    "mamba": jax.tree.map(lambda t: (None, None, *t), m, is_leaf=is_t),
                 }
             if n_tail:
-                cache["tail"] = {
-                    "shared": to_p(a),
-                    "mamba": tuple(to_p(m) for _ in range(n_tail)),
-                }
+                cache["tail"] = {"shared": a, "mamba": tuple(m for _ in range(n_tail))}
         elif self.is_rwkv:
             s = rwkv6.rwkv6_state_specs(cfg)
-            stacked_s = jax.tree.map(
-                lambda t: (None, *t), s, is_leaf=lambda t: isinstance(t, tuple)
-            )
-            cache = {"layers": to_p(stacked_s)}
+            cache = {"layers": jax.tree.map(lambda t: (None, *t), s, is_leaf=is_t)}
         else:
             a = attn.attn_cache_specs(cfg, cp=cp)
-            stacked_a = {
-                "kv": to_p(
-                    jax.tree.map(lambda t: (None, *t), a, is_leaf=lambda t: isinstance(t, tuple))
-                )
-            }
-            cache = {"layers": stacked_a}
+            cache = {"layers": {"kv": jax.tree.map(lambda t: (None, *t), a, is_leaf=is_t)}}
             n_head = cfg.moe.first_k_dense if cfg.moe else 0
             if n_head:
-                cache["head_layers"] = {str(i): {"kv": to_p(a)} for i in range(n_head)}
-        cache["pos"] = logical_spec()
+                cache["head_layers"] = {str(i): {"kv": a} for i in range(n_head)}
+        cache["pos"] = ()
         return cache
+
+    @staticmethod
+    def _is_axes(t) -> bool:
+        # a logical-axes tuple: entries are names, None, or tuples of names
+        return isinstance(t, tuple) and all(
+            isinstance(n, (str, type(None)))
+            or (isinstance(n, tuple) and all(isinstance(m, str) for m in n))
+            for n in t
+        )
+
+    def cache_pspecs(self, cp: bool = False):
+        """PartitionSpec tree matching init_cache structure (for pjit shardings).
+
+        Leaves are PartitionSpec (resolved under the current sharding rules).
+        """
+        from repro.parallel.axes import logical_spec
+
+        return jax.tree.map(
+            lambda names: logical_spec(*names), self._cache_axes(cp), is_leaf=self._is_axes
+        )
+
+    def cache_batch_axes(self):
+        """Tree matching init_cache structure: each leaf's batch axis (the KV
+        caches' is neither leading nor second), None for the scalar "pos"."""
+        return jax.tree.map(
+            lambda names: names.index("dp") if "dp" in names else None,
+            self._cache_axes(),
+            is_leaf=self._is_axes,
+        )
 
     def cache_shapes(self, batch_size: int, max_len: int, cp: bool = False):
         """ShapeDtypeStruct tree of the decode cache (no allocation; AOT)."""

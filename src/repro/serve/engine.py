@@ -6,15 +6,17 @@ Implements the paper's two inference phases as separate compiled programs:
 
 Slot-based continuous batching (lite): a fixed decode batch of `slots`; each
 finished request frees its slot, queued prompts are prefilled into free slots
-and their caches spliced in. Cache buffers are donated across decode steps,
-yet the update is not in place: the one-position write (`attn/kv_write`) is
-cheap, but the layer scan slices each layer's K and V out of the stacked
-cache and writes them back whole, and the program copies the stacked cache
-on entry. In a profiler trace on TPU v5e (starcoder2-3b, 16 slots, 4096-token
-cache) those copies take about 30 ms of a 44-ms step, outside every named
-scope. Limitation (recorded): the cache position is a single scalar, so
-admitted prompts are aligned to the current position — adequate for the
-near-equal-length request mixes the benchmarks use.
+and their caches spliced in along each leaf's batch axis, which the model
+names (`Model.cache_batch_axes`). Cache buffers are donated across decode
+steps and updated in place: the layer scan carries the stacked KV cache and
+each layer writes its one position into it (`attn/kv_write`), and the cache
+is stored in the (position, KV head, batch, head dim) order the TPU keeps it
+in, so that at the benchmark's 8 and 16 slots the program neither copies it
+nor relays it out at its edges (at one slot the TPU compiler still relays the
+cache out on entry and back on exit). Limitation (recorded): the cache
+position is a single scalar, so admitted prompts are aligned to the current
+position — adequate for the near-equal-length request mixes the benchmarks
+use.
 
 The programs lower as `jit_prefill` and `jit_decode`. `serve()` writes host
 spans into the profiler's trace (inactive when no trace runs): `engine.admit`
@@ -51,6 +53,7 @@ class ServeEngine:
         self.max_len = max_len
         self.slots = slots
         self.key = jax.random.PRNGKey(seed)
+        self._batch_axes = model.cache_batch_axes()
         # of the last serve() call: decode iterations, and host seconds spent
         # in prefill (with the cache splice) and in decode, each up to the
         # logits' arrival on the host; of those, the host's own work: the
@@ -164,24 +167,19 @@ class ServeEngine:
             new_logits = np.array(logits)
         if cache is None:
             return new_cache, new_logits
-        # splice: batch dim is leading on every cache leaf except "pos"
         mask = np.zeros((B,), bool)
         for s in slots_to_fill:
             mask[s] = True
         m = jnp.asarray(mask)
 
-        def splice(old, new):
-            if old.ndim == 0:  # pos: keep max (slots decode in lockstep)
+        def splice(old, new, axis):
+            if axis is None:  # pos: keep max (slots decode in lockstep)
                 return jnp.maximum(old, new)
-            if old.shape[0] == B:
-                sel = m.reshape((B,) + (1,) * (old.ndim - 1))
-                return jnp.where(sel, new, old)
-            # stacked-layer leaves: (L, B, ...)
-            sel = m.reshape((1, B) + (1,) * (old.ndim - 2))
+            sel = m.reshape([B if d == axis else 1 for d in range(old.ndim)])
             return jnp.where(sel, new, old)
 
         with TraceAnnotation("engine.splice"):
-            cache = jax.tree.map(splice, cache, new_cache)
+            cache = jax.tree.map(splice, cache, new_cache, self._batch_axes)
             if logits_np is not None:
                 logits_np[mask] = new_logits[mask]
         return cache, logits_np

@@ -72,5 +72,5 @@ def test_decode_swa_ring_buffer_positions():
         logits, cache = dec(params, cache, tokens[:, t : t + 1])
         errs.append(np.abs(np.asarray(logits) - ref[:, t]).max())
     # cache holds only 8 slots yet matches the full-window forward exactly
-    assert cache["layers"]["kv"]["k"].shape[2] == 8
+    assert cache["layers"]["kv"]["k"].shape[1] == 8
     assert max(errs) < 2e-3

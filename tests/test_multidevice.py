@@ -1,7 +1,8 @@
 """Multi-device tests (subprocess: device count must be set before jax init).
 
 Covers: sharded training == single-device numerics, multi-pod mesh train step,
-elastic checkpoint reshard (1 device save -> 8 device restore)."""
+elastic checkpoint reshard (1 device save -> 8 device restore), sharded decode
+== single-device decode with the cache keeping its sharding across steps."""
 
 import os
 import subprocess
@@ -155,3 +156,60 @@ def test_grad_compression_under_mesh():
         """
     )
     assert "COMPRESS_OK" in out
+
+
+# (mesh shape, context parallel, KV heads): heads over tp; a sequence-sharded
+# cache where 2 KV heads do not divide tp=4; sequence over cp and tp
+DECODE_MESHES = [((2, 2), False, 2), ((1, 4), False, 2), ((2, 2), True, 1)]
+
+
+@pytest.mark.parametrize("mesh_shape,cp,kv", DECODE_MESHES)
+def test_sharded_decode_matches_single_device(mesh_shape, cp, kv):
+    """Decode steps under a mesh give the single-device logits, and the cache
+    each step returns keeps the sharding `cache_pspecs` gives it, so the next
+    step takes it as its input unchanged."""
+    out = _run(
+        f"""
+        import dataclasses
+        import jax, numpy as np
+        from jax.sharding import NamedSharding
+        from repro.configs import get_config
+        from repro.launch.mesh import make_mesh
+        from repro.models.transformer import Model
+        from repro.parallel.axes import make_rules, sanitize_spec_tree, use_mesh
+
+        cp = {cp}
+        cfg = dataclasses.replace(get_config("starcoder2-3b").reduced(), num_kv_heads={kv})
+        m = Model(cfg)
+        params = m.init(jax.random.PRNGKey(0))
+        B, S, n, max_len = 4, 16, 4, 32
+        toks = jax.random.randint(jax.random.PRNGKey(1), (B, S + n), 0, cfg.vocab_size)
+
+        def decode(prefill, step):
+            logits, cache = prefill(params, {{"tokens": toks[:, :S]}})
+            out = []
+            for t in range(S, S + n):
+                logits, cache = step(params, cache, toks[:, t:t + 1])
+                out.append(np.asarray(logits))
+            return out
+
+        ref = decode(jax.jit(lambda p, b: m.prefill(p, b, max_len=max_len)),
+                     jax.jit(m.decode_step))
+        mesh = make_mesh({mesh_shape}, ("data", "model"))
+        rules = make_rules(dp=() if cp else ("data",), tp=("model",),
+                           context_parallel=("data",) if cp else ())
+        with use_mesh(mesh, rules):
+            specs = sanitize_spec_tree(m.cache_pspecs(cp=cp), m.cache_shapes(B, max_len, cp=cp), mesh)
+            shardings = jax.tree.map(lambda s: NamedSharding(mesh, s), specs)
+            prefill = jax.jit(lambda p, b: jax.device_put(
+                m.prefill(p, b, max_len=max_len, cp=cp), (None, shardings)))
+            step = jax.jit(lambda p, c, t: m.decode_step(p, c, t, cp=cp),
+                           in_shardings=(None, shardings, None), donate_argnums=(1,))
+            got = decode(prefill, step)
+        err = max(float(np.abs(a - b).max()) for a, b in zip(ref, got))
+        assert err < 1e-4, err
+        print("SHARDED_DECODE_MATCH", err)
+        """,
+        devices=4,
+    )
+    assert "SHARDED_DECODE_MATCH" in out

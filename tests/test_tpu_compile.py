@@ -128,6 +128,28 @@ def test_starcoder2_decode_fits_one_chip(one_chip, starcoder_engine):
     assert _total_bytes(compiled) < HBM_BYTES
 
 
+# the chip benchmark's decode shapes: (config, its changed sizes, slots, max_len)
+DECODE_CELLS = {
+    "starcoder2-3b": ("starcoder2-3b", {}, 16, 4096),
+    "minitron-8b-l16": ("minitron-8b", {"num_layers": 16, "num_heads": 48}, 8, 4096),
+}
+
+
+@pytest.mark.parametrize("cell", list(DECODE_CELLS))
+def test_decode_updates_cache_in_place(one_chip, cell):
+    """The donated KV cache is updated in place: the decode program holds no
+    second copy of it (a scan that slices and rewrites each layer's cache, or
+    a relayout at the program's edges, needs one)."""
+    arch, sizes, slots, max_len = DECODE_CELLS[cell]
+    model = Model(dataclasses.replace(get_config(arch), **sizes))
+    eng = ServeEngine(model, None, max_len=max_len, slots=slots)
+    cache = model.cache_shapes(slots, max_len)
+    compiled = eng._decode.lower(_on(model.pshapes(), one_chip), _on(cache, one_chip),
+                                 _spec((slots, 1), jnp.int32, one_chip)).compile()
+    kv_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(cache["layers"]["kv"]))
+    assert compiled.memory_analysis().temp_size_in_bytes < kv_bytes / 4
+
+
 def test_starcoder2_train_step_fits_one_chip(one_chip):
     """chip_smoke.py's training size: 4 layers at published widths, AdamW,
     selective remat, batch 2 x 4096."""
