@@ -10,9 +10,10 @@ control gives. Each seed runs the cell's own load, as many whole waves of the
 mix as it takes to hold `check_requests` requests, and reads `logit_gap` over a
 sample of as many requests as a run compares.
 
-- control: the reference in float8 (`reference.py`, mode "fp8") in the
-  program's place: at each position of the same prompts and served tokens,
-  the gap of the token the control puts first.
+- control: the reference of the family the configuration names
+  (`families/<name>.py`) in float8, mode "fp8", in the program's place: at
+  each position of the same prompts and served tokens, the gap of the token
+  the control puts first.
 - faults, on the first `FAULT_SEEDS` seeds: `altered`, a served token
   replaced by the next id, read at the first served position; `stale_cache`,
   a decode step that returns the cache it was given.
@@ -39,13 +40,12 @@ def serve_readings(ctx, seeds, log):
     import jax.numpy as jnp
     import numpy as np
 
-    import reference
     import traffic
     import weights
     from repro.models.transformer import Model
     from repro.serve.engine import Request, ServeEngine
 
-    cfg, mix = ctx["config"], ctx["mix"]
+    cfg, mix, fam = ctx["config"], ctx["mix"], ctx["family"]
     S, B, new, V, k = (mix["prompt_len"], mix["slots"], mix["new_tokens"], cfg["vocab_size"],
                        mix["check_requests"])
     model = Model(ctx["model_config"])
@@ -59,7 +59,7 @@ def serve_readings(ctx, seeds, log):
         runs = (("program", decode), ("stale_cache", stale))
         for fault, dec in runs[: 2 if len(out) < FAULT_SEEDS else 1]:
             engine._decode = dec
-            engine.params = weights.make(cfg, seed)
+            engine.params = weights.make(fam, cfg, seed)
             reqs = []
             t0 = time.perf_counter()
             for w in range(waves):
@@ -75,7 +75,7 @@ def serve_readings(ctx, seeds, log):
             seqs = np.stack([np.concatenate([finished[i][0], finished[i][1][:-1]]) for i in pick])
             served = jnp.asarray(np.stack([finished[i][1] for i in pick]))
             t0 = time.perf_counter()
-            ref = reference.scored_logits(cfg, seed, seqs, S - 1)
+            ref = fam.scored_logits(cfg, seed, seqs, S - 1)
             best = ref.max(-1).block_until_ready()
             ref_s = time.perf_counter() - t0
 
@@ -85,7 +85,7 @@ def serve_readings(ctx, seeds, log):
             if fault == "program":
                 row.update(program=float(gap(served).max()), wave_s=wave_s, ref_s=ref_s,
                            altered=float(gap((served + 1) % V)[:, 0].min()))
-                ctl = reference.scored_logits(cfg, seed, seqs, S - 1, mode="fp8")
+                ctl = fam.scored_logits(cfg, seed, seqs, S - 1, mode="fp8")
                 row["control"] = float(gap(jnp.argmax(ctl, -1)).max())
                 del ctl
             else:
@@ -111,6 +111,7 @@ def main(argv=None) -> int:
     cfg = json.loads((root / harness.entry(bench["configs"], cell["config"], "config")["file"]).read_text())
     import jax
 
+    import family
     import traffic
 
     dev = jax.devices()[0]
@@ -120,7 +121,9 @@ def main(argv=None) -> int:
     harness.enable_compile_cache(root)
     sys.path.insert(0, str(harness.ROOT / "src"))
     mix = traffic.load(base, cell["traffic"])
-    ctx = {"config": cfg, "mix": mix, "model_config": harness.model_config(cfg)}
+    fam = family.load(base, cfg)
+    ctx = {"config": cfg, "mix": mix, "family": fam,
+           "model_config": harness.model_config(cfg, fam)}
     rows = serve_readings(ctx, seeds, harness.log)
     Path(args.out).parent.mkdir(parents=True, exist_ok=True)
     Path(args.out).write_text(json.dumps({"workload": args.workload, "device": dev.device_kind,

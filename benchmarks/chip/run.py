@@ -7,7 +7,11 @@ entries of `BENCHMARK.json` at the checkout's root; everything that belongs to
 one configuration, traffic mix, metric or cell is a file of its own here,
 found by its name:
 
-  configs/<config>.json    the sizes as run, with source, cuts and departures
+  configs/<config>.json    the sizes as run, with source, cuts and departures, and
+                           the family it names (`"reference"`)
+  families/<family>.py     what is specific to one architecture: the sizes it
+                           covers, its parameter tree, its plain reference, its
+                           work counts and its named scopes (`family.py`)
   traffic/<traffic>.json   the mix's parameters, read by `traffic.py`
   metrics/<metric>.py      a per-layer metric's reader: read(run) -> value or None
   limits/<cell>.json       the limit of each number the correctness check compares
@@ -27,7 +31,6 @@ import time
 T0 = time.perf_counter()
 
 import argparse  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import math  # noqa: E402
 import os  # noqa: E402
@@ -42,11 +45,8 @@ ROOT = HERE.parents[1]
 sys.path.insert(0, str(HERE))
 
 # keys of a configuration file that are not sizes of the model
-META = ("source", "arch", "deployment", "reduced", "assumed", "departures", "compile_rehearsal")
-# what the plain reference implements; any other model is refused
-REFERENCE_COVERS = {"family": "dense", "norm": "layernorm", "gated_mlp": False, "qk_norm": False,
-                    "sliding_window": None, "moe": None, "ssm": None, "attn_every": None,
-                    "input_mode": "tokens"}
+META = ("source", "arch", "deployment", "reduced", "assumed", "departures", "compile_rehearsal",
+        "reference")
 
 
 def log(*a):
@@ -77,8 +77,9 @@ def applies(metric: dict, cell: str, e2e_names=None) -> bool:
     return e2e_names is None or metric["moves"] in e2e_names
 
 
-def model_config(cfg: dict):
-    """The program's config for the file's arch, with every size of the file."""
+def model_config(cfg: dict, fam):
+    """The program's config for the file's arch, with every size of the file;
+    refused where the family `fam` does not cover it."""
     from repro.configs import get_config
 
     base = get_config(cfg["arch"])
@@ -87,18 +88,16 @@ def model_config(cfg: dict):
     if unknown:
         raise KeyError(f"configuration keys the program does not have: {unknown}")
     mcfg = replace(base, **sizes)
-    off = {k: getattr(mcfg, k) for k, v in REFERENCE_COVERS.items() if getattr(mcfg, k) != v}
+    off = {k: getattr(mcfg, k) for k, v in fam.COVERS.items() if getattr(mcfg, k) != v}
     if off:
-        raise ValueError(f"the reference does not cover {off}")
+        raise ValueError(f"family {cfg['reference']!r} does not cover {off}")
     return mcfg
 
 
 def load_reader(base: Path, name: str):
-    path = base / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"metric_{name}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    import family
+
+    return family.load_file(base / "metrics" / f"{name}.py", f"metric_{name}").read
 
 
 class CompileClock:
@@ -162,6 +161,26 @@ def peak_memory(devices) -> int | None:
     return max(peaks) if peaks else None
 
 
+def reduce_scopes(xplane: str, hlo, fam) -> dict | None:
+    """`scopes.reduce_xplane` of the trace by the family's named scopes; a
+    failure is logged and reads None, so that only the readers of scopes lose."""
+    import scopes
+
+    t0 = time.perf_counter()
+    try:
+        got = scopes.reduce_xplane(xplane, scopes.scope_map(hlo or [], fam.SCOPES))
+    except Exception as e:  # the run still prints its line, without the scope readers
+        log(f"scopes: the reduction failed: {type(e).__name__}: {e}")
+        return None
+    if got is None:
+        log("scopes: no annotated window or no device operation in the trace")
+        return None
+    runs = {n: p["runs"] for n, p in got["programs"].items()}
+    log(f"scopes: reduced in {time.perf_counter() - t0:.3f} s; clock offset "
+        f"{got['clock_offset_ms']:.4f} ms, bounds {got['clock_bounds_ms']} ms; runs {runs}")
+    return got
+
+
 def main(argv=None, *, root: Path = ROOT, base: Path = HERE, require_tpu: bool = True,
          compile_cache: bool = True, t0: float = T0) -> int:
     """Run the cell; `root` holds BENCHMARK.json, `base` the harness's data
@@ -171,8 +190,10 @@ def main(argv=None, *, root: Path = ROOT, base: Path = HERE, require_tpu: bool =
     cell = entry(bench["workloads"], args.workload, "workload")
     cfg_entry = entry(bench["configs"], cell["config"], "config")
     cfg = json.loads((root / cfg_entry["file"]).read_text())
+    import family
     import traffic
 
+    fam = family.load(base, cfg)
     mix = traffic.load(base, cell["traffic"])
     limits_path = base / "limits" / f"{cell['name']}.json"
     limits = json.loads(limits_path.read_text()) if limits_path.exists() else {}
@@ -190,9 +211,9 @@ def main(argv=None, *, root: Path = ROOT, base: Path = HERE, require_tpu: bool =
     peak = peaks.peaks(kind) if require_tpu else peaks.PEAKS.get(kind)
     cache = enable_compile_cache(root) if compile_cache else "off"
     sys.path.insert(0, str(ROOT / "src"))
-    mcfg = model_config(cfg)
-    log(f"cell {cell['name']}: {cfg['arch']} {cfg['num_layers']} layers, traffic {mix}, "
-        f"device {kind} x{len(devices)}, compile cache {cache}")
+    mcfg = model_config(cfg, fam)
+    log(f"cell {cell['name']}: {cfg['arch']} ({cfg['reference']} family), {cfg['num_layers']} "
+        f"layers, traffic {mix}, device {kind} x{len(devices)}, compile cache {cache}")
     if "compile_rehearsal" in cfg:
         log("compile rehearsal (described v5e, before any chip run):", cfg["compile_rehearsal"])
 
@@ -220,7 +241,7 @@ def main(argv=None, *, root: Path = ROOT, base: Path = HERE, require_tpu: bool =
     import serving
 
     ctx = {"config": cfg, "mix": mix, "seed": args.seed, "seconds": args.seconds,
-           "model_config": mcfg, "tracer": tracer, "setup_done": setup_done,
+           "model_config": mcfg, "family": fam, "tracer": tracer, "setup_done": setup_done,
            "window_done": window_done, "predict": predict}
     try:
         out = serving.run(ctx)
@@ -240,11 +261,14 @@ def main(argv=None, *, root: Path = ROOT, base: Path = HERE, require_tpu: bool =
                    for m in bench["end_to_end"] if m["name"] in e2e_names}
     else:
         record = {**out["record"], "compile_s": marks["compile_s"], "chips": cell["chips"],
-                  "config": cfg, "mix": mix, "peak": peak, "trace": None}
+                  "config": cfg, "mix": mix, "peak": peak, "trace": None, "scopes": None}
+        hlo = record.pop("hlo", None)
         if tracer.dir:
             import devtrace
 
-            record["trace"] = devtrace.reduce_xplane(devtrace.find_xplane(tracer.dir))
+            xplane = devtrace.find_xplane(tracer.dir)
+            record["trace"] = devtrace.reduce_xplane(xplane)
+            record["scopes"] = reduce_scopes(xplane, hlo, fam)
             shutil.rmtree(tracer.dir, ignore_errors=True)
         metrics = {}
         for m in bench["per_layer"]:

@@ -4,8 +4,8 @@ Three reductions of a profiler trace (`.xplane.pb`) of the harness's `wave`
 window, beside `devtrace.py`'s whole-window numbers:
 
 - scope map: from each compiled program's HLO text, the named scope of every
-  instruction (`%fusion.4` -> `attn/kv_write`): the deepest of `SCOPES` and,
-  under `attn`, of `ATTN_PARTS` in its `metadata={op_name=...}`, else
+  instruction (`%fusion.4` -> `attn/kv_write`): the deepest of the family's
+  `SCOPES` (`families/<name>.py`) in its `metadata={op_name=...}`, else
   `unscoped`;
 - device time: the device's `XLA Modules` line gives each program execution,
   and each operation of the `XLA Ops` line is given to the execution that
@@ -29,9 +29,10 @@ leave the offset at 0, and the reduction says so.
 Seconds are averaged over the devices. With no engine span or named program
 in the trace (a program without them) the reduction still runs.
 
-`run.py` does not call this module yet: it reduces the trace with
-`devtrace.py` alone and removes it before the readers run. The tests call it,
-on synthetic events and on the engine trace that
+`run.py` reduces a `--trace 1` run's trace by it, beside `devtrace.py`, and
+hands the result to the readers as `scopes`, with the scope map of the
+programs `serving.memory_analysis` compiles after the window. The tests call
+it on synthetic events and on the engine trace that
 `tests/chip_bench/record_engine_trace.py` records on the chip.
 """
 
@@ -43,8 +44,6 @@ from collections import defaultdict
 
 import devtrace
 
-SCOPES = ("embed", "norm", "attn", "mlp", "head")
-ATTN_PARTS = ("qkv", "kv_write", "core", "out")
 UNSCOPED = "unscoped"
 OUTSIDE = "outside engine"
 SPAN = "engine."
@@ -62,22 +61,36 @@ def log(*a):
     print(*a, file=sys.stderr, flush=True)
 
 
-def scope_of(op_name: str) -> str:
-    """`jit(decode)/while/body/closed_call/attn/kv_write/dynamic_update_slice`
-    -> `attn/kv_write`; a name with none of the scopes -> `unscoped`."""
+def scope_of(op_name: str, vocab) -> str:
+    """The deepest scope of `vocab` (a family's `SCOPES`) that `op_name` names:
+    with `attn` and `attn/kv_write` in it,
+    `jit(decode)/while/body/closed_call/attn/kv_write/dynamic_update_slice`
+    -> `attn/kv_write`; a name with none of the scopes -> `unscoped`. A part
+    counts only inside its scope, and takes the place of a sibling part."""
     scope = UNSCOPED
     for part in op_name.split("/"):
-        if part in SCOPES:
+        if part in vocab:
             scope = part
-        elif part in ATTN_PARTS and scope.startswith("attn"):
-            scope = "attn/" + part
+            continue
+        outer = scope
+        while outer != UNSCOPED:
+            if f"{outer}/{part}" in vocab:
+                scope = f"{outer}/{part}"
+                break
+            outer = outer.rpartition("/")[0] or UNSCOPED
     return scope
 
 
-def scope_map(hlo_texts) -> dict:
+def scope_map(hlo_texts, vocab=None) -> dict:
     """{module name: {"%instruction": scope}} from compiled HLO texts, each
-    holding one module or more; an instruction without metadata is
-    `unscoped`."""
+    holding one module or more, by the scopes of `vocab`, a family's `SCOPES`
+    (by default the dense family's, which the program's own tests read); an
+    instruction without metadata is `unscoped`."""
+    if vocab is None:
+        import family
+
+        vocab = family.load(family.HERE, {"reference": "dense"}).SCOPES
+    vocab = frozenset(vocab)
     out: dict = {}
     for text in hlo_texts:
         for module in _MODULE_START.split(text):
@@ -86,14 +99,16 @@ def scope_map(hlo_texts) -> dict:
                 names = out.setdefault(m.group(1), {})
                 for name, rest in _INSTR.findall(module):
                     op_name = _OP_NAME.search(rest)
-                    names["%" + name] = scope_of(op_name.group(1)) if op_name else UNSCOPED
+                    names["%" + name] = scope_of(op_name.group(1), vocab) if op_name else UNSCOPED
     return out
 
 
 def compiled_texts(engine, model, mix) -> list[str]:
     """The compiled HLO text of the engine's two programs at the mix's shapes
     (`slots`, `prompt_len`, `max_len`), lowered as `serving.memory_analysis`
-    lowers them; `scope_map` reads it."""
+    lowers them; `scope_map` reads it. A run takes the same text from the
+    programs that `memory_analysis` compiles; the engine trace's recorder and
+    the program's tests call this."""
     import jax
     import jax.numpy as jnp
 
@@ -241,3 +256,25 @@ def reduce_events(host, devices, smap, annotations=devtrace.ANNOTATIONS) -> dict
         "idle": {k: v / n for k, v in sorted(idle.items(), key=lambda kv: -kv[1])},
         "spans": dict(counts),
     }
+
+
+def program_ms(run: dict, program: str, scope: str) -> float | None:
+    """Milliseconds of device time in `scope` per run of `program`, from a run
+    record's `scopes` (`reduce_events`); None where the record has no
+    reduction, no run of the program or no time in the scope."""
+    prog = (run.get("scopes") or {}).get("programs", {}).get(program)
+    if not prog or not prog["runs"] or scope not in prog["scopes"]:
+        return None
+    return 1e3 * prog["scopes"][scope] / prog["runs"]
+
+
+def idle_ms(run: dict, spans) -> float | None:
+    """Milliseconds per decode program run in which the device idles under any
+    of the engine's `spans`; None where the record has no reduction, no decode
+    run, or a trace without one of the spans (a program that does not write
+    them)."""
+    got = run.get("scopes")
+    prog = got and got["programs"].get(DECODE)
+    if not prog or not prog["runs"] or not all(got["spans"].get(s) for s in spans):
+        return None
+    return 1e3 * sum(got["idle"].get(s, 0.0) for s in spans) / prog["runs"]

@@ -9,9 +9,12 @@ prefill returns (`engine.prefill_s` later). With `--trace 1` the profiler runs
 over the first whole waves of `TRACE_SECONDS` or more; each wave records
 whether it was traced, so that host-clock readers can leave those out.
 
-After the window the program's state is freed and the plain reference reads a
-sample of the finished requests drawn from the seed: for each served token,
-how far its logit lies below the reference's best at that position.
+After the window the program's state is freed and the plain reference of the
+configuration's family (`families/<name>.py`) reads a sample of the finished
+requests drawn from the seed: for each served token, how far its logit lies
+below the reference's best at that position. A `--trace 1` run also keeps the
+compiled HLO text of the programs that `memory_analysis` compiles after the
+window, from which `run.py` maps device time to the family's named scopes.
 """
 
 from __future__ import annotations
@@ -24,7 +27,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-import reference
 import traffic
 import weights
 
@@ -45,8 +47,9 @@ def prediction(mcfg, mix) -> dict:
             "gen_tokens_per_s": mix["slots"] * mix["new_tokens"] / b.total}
 
 
-def memory_analysis(engine, model, mix) -> dict:
-    """What XLA reserves for each of the engine's programs, where they can be lowered."""
+def memory_analysis(engine, model, mix, with_text: bool = False) -> tuple[dict, list[str]]:
+    """What XLA reserves for each of the engine's programs, where they can be
+    lowered, and with `with_text` their compiled HLO text."""
     S, B = mix["prompt_len"], mix["slots"]
     params = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), engine.params)
     args = {
@@ -54,30 +57,33 @@ def memory_analysis(engine, model, mix) -> dict:
         "_decode": (params, model.cache_shapes(B, mix["max_len"]),
                     jax.ShapeDtypeStruct((B, 1), jnp.int32)),
     }
-    out = {}
+    out, texts = {}, []
     for name, a in args.items():
         fn = getattr(engine, name, None)
         if not hasattr(fn, "lower"):
             out[name] = "not a jitted function"
             continue
-        m = fn.lower(*a).compile().memory_analysis()
+        compiled = fn.lower(*a).compile()
+        if with_text:
+            texts.append(compiled.as_text())
+        m = compiled.memory_analysis()
         out[name] = {k: getattr(m, k) for k in ("argument_size_in_bytes", "output_size_in_bytes",
                                                  "temp_size_in_bytes", "generated_code_size_in_bytes")}
-    return out
+    return out, texts
 
 
 def run(ctx) -> dict:
     from repro.models.transformer import Model
     from repro.serve.engine import Request, ServeEngine
 
-    cfg, mix, seed = ctx["config"], ctx["mix"], ctx["seed"]
+    cfg, mix, seed, fam = ctx["config"], ctx["mix"], ctx["seed"], ctx["family"]
     S, B, new, V = mix["prompt_len"], mix["slots"], mix["new_tokens"], cfg["vocab_size"]
     log("prediction (repro.core, tpu-v5e):", ctx["predict"](lambda: prediction(ctx["model_config"], mix)))
 
     t0 = time.perf_counter()
     model = Model(ctx["model_config"])
-    weights.check_tree(cfg, model.pshapes())
-    params = jax.block_until_ready(weights.make(cfg, seed))
+    weights.check_tree(fam, cfg, model.pshapes())
+    params = jax.block_until_ready(weights.make(fam, cfg, seed))
     t1 = time.perf_counter()
     engine = ServeEngine(model, params, max_len=mix["max_len"], slots=B)
     del params
@@ -104,6 +110,8 @@ def run(ctx) -> dict:
         t1 = time.perf_counter()
         waves.append({"t0": t0, "t1": t1, "traced": traced, "prefill_s": engine.prefill_s,
                       "decode_s": engine.decode_s, "decode_steps": engine.decode_steps,
+                      "sample_s": getattr(engine, "sample_s", None),
+                      "dispatch_s": getattr(engine, "dispatch_s", None),
                       "tokens": [len(r.out_tokens) for r in reqs]})
         finished += [(r.prompt, list(r.out_tokens), r.done) for r in reqs]
         log(f"wave {len(waves) - 1}: {t1 - t0:.4f} s, prefill {engine.prefill_s:.4f} s, "
@@ -117,7 +125,9 @@ def run(ctx) -> dict:
     log(f"host CPU in the window: {time.process_time() - cpu0:.3f} s "
         f"over {time.perf_counter() - w0:.3f} s")
 
-    log("memory_analysis:", memory_analysis(engine, model, mix))
+    keep_hlo = ctx["tracer"].on
+    analysis, hlo = memory_analysis(engine, model, mix, with_text=keep_hlo)
+    log("memory_analysis:", analysis)
     del engine
     gc.unfreeze()
     gc.collect()
@@ -131,10 +141,13 @@ def run(ctx) -> dict:
     log(f"window: {len(waves)} waves, {len(finished)} requests, {window:.3f} s")
 
     t0 = time.perf_counter()
-    checks = {"logit_gap": served_gap(cfg, seed, finished, S, new, mix["check_requests"])}
+    checks = {"logit_gap": served_gap(fam, cfg, seed, finished, S, new, mix["check_requests"])}
     log(f"reference: {mix['check_requests']} requests compared in {time.perf_counter() - t0:.3f} s")
+    record = {"waves": waves, "prompt_len": S, "slots": B}
+    if keep_hlo:
+        record["hlo"] = hlo
     return {"e2e": e2e, "checks": checks, "attempted": len(finished), "failed": failed,
-            "record": {"waves": waves, "prompt_len": S, "slots": B}}
+            "record": record}
 
 
 def request_ms(waves) -> tuple[list[float], list[float]]:
@@ -150,7 +163,7 @@ def request_ms(waves) -> tuple[list[float], list[float]]:
     return ttft, tpot
 
 
-def served_gap(cfg, seed, finished, S, new, k) -> float:
+def served_gap(fam, cfg, seed, finished, S, new, k) -> float:
     """Widest gap, over a seeded sample of finished requests, by which a served
     token's logit lies below the reference's best at its position."""
     pick = [i for i in traffic.check_sample(seed, len(finished), k) if len(finished[i][1]) == new]
@@ -158,6 +171,6 @@ def served_gap(cfg, seed, finished, S, new, k) -> float:
         return float("inf")
     seqs = np.stack([np.concatenate([finished[i][0], finished[i][1][:-1]]) for i in pick])
     served = jnp.asarray(np.stack([finished[i][1] for i in pick]))
-    ref = reference.scored_logits(cfg, seed, seqs, S - 1)
+    ref = fam.scored_logits(cfg, seed, seqs, S - 1)
     gap = ref.max(-1) - jnp.take_along_axis(ref, served[..., None], -1)[..., 0]
     return float(gap.max())

@@ -1,13 +1,14 @@
-"""Seeded weights for the dense decoder, made on the device in one jitted call.
+"""Seeded weights, made on the device in one jitted call.
 
 The benchmark makes the weights, not the program: the program is handed them,
 and the plain reference draws the same values again from the seed, layer by
 layer, so that it takes nothing the program has made.
 
-`layout(cfg)` is the parameter tree the program's dense decoder reads, as
-{path: (shape, dtype name)}; the harness refuses to run when the program's own
-tree differs from it. Every leaf is drawn from its own key, and each layer of a
-stacked leaf from a key of its own, so that one layer can be drawn alone.
+The tree is the family's `layout(cfg)` (`families/<name>.py`), the parameter
+tree the program reads, as {path: (shape, dtype name)}; the harness refuses to
+run when the program's own tree differs from it. Every leaf is drawn from its
+own key, and each layer of a stacked leaf (`layers/...`) from a key of its
+own, so that one layer can be drawn alone.
 """
 
 from __future__ import annotations
@@ -32,32 +33,6 @@ def seed_key(seed: int) -> jax.Array:
         raise ValueError(f"seed {seed} is not in [0, 2**64)")
     words = np.array([seed >> 32, seed & 0xFFFFFFFF], np.uint32)
     return jax.random.wrap_key_data(words, impl="threefry2x32")
-
-
-def layout(cfg: dict) -> dict[str, tuple[tuple[int, ...], str]]:
-    """The dense decoder's parameter tree: LayerNorm, GQA attention, plain MLP,
-    and a head of its own unless `tie_embeddings`. `cfg` is a configuration
-    file's dict."""
-    L, d, V, ff = cfg["num_layers"], cfg["d_model"], cfg["vocab_size"], cfg["d_ff"]
-    q, kv = cfg["num_heads"] * cfg["head_dim"], cfg["num_kv_heads"] * cfg["head_dim"]
-    p, f = cfg["param_dtype"], "float32"
-    out = {
-        "embed/tok": ((V, d), p),
-        "final_norm/scale": ((d,), f),
-        "final_norm/bias": ((d,), f),
-    }
-    if not cfg["tie_embeddings"]:
-        out["lm_head/w"] = ((d, V), p)
-    per_layer = {
-        "ln1/scale": ((d,), f), "ln1/bias": ((d,), f),
-        "attn/wq": ((d, q), p), "attn/wk": ((d, kv), p),
-        "attn/wv": ((d, kv), p), "attn/wo": ((q, d), p),
-        "ln2/scale": ((d,), f), "ln2/bias": ((d,), f),
-        "mlp/wi": ((d, ff), p), "mlp/wo": ((ff, d), p),
-    }
-    for name, (shape, dt) in per_layer.items():
-        out[f"layers/{name}"] = ((L, *shape), dt)
-    return out
 
 
 def _leaf_key(key, path: str):
@@ -116,22 +91,23 @@ def flatten(tree, prefix: str = "") -> dict:
     return out
 
 
-def build(cfg: dict, key) -> dict:
+def build(family, cfg: dict, key) -> dict:
     """The parameter tree of `cfg` for `key` (traceable)."""
     tied = cfg["tie_embeddings"]
-    return nest({p: leaf(key, p, *spec, tied) for p, spec in layout(cfg).items()})
+    return nest({p: leaf(key, p, *spec, tied) for p, spec in family.layout(cfg).items()})
 
 
-def make(cfg: dict, seed: int) -> dict:
+def make(family, cfg: dict, seed: int) -> dict:
     """The parameter tree of `cfg` for `seed`, in one jitted call on the device;
     the key is an argument, so one compiled program serves every seed."""
-    return jax.jit(lambda key: build(cfg, key))(seed_key(seed))
+    return jax.jit(lambda key: build(family, cfg, key))(seed_key(seed))
 
 
-def check_tree(cfg: dict, shapes) -> None:
-    """Raise unless the program's parameter tree (ShapeDtypeStructs) is `layout`."""
+def check_tree(family, cfg: dict, shapes) -> None:
+    """Raise unless the program's parameter tree (ShapeDtypeStructs) is the
+    family's `layout`."""
     got = {p: (tuple(s.shape), str(s.dtype)) for p, s in flatten(shapes).items()}
-    want = layout(cfg)
+    want = family.layout(cfg)
     if got != want:
         diff = sorted(set(got.items()) ^ set(want.items()))
         raise RuntimeError(f"the program's parameter tree is not the benchmark's layout: {diff}")

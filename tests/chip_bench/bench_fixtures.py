@@ -26,6 +26,8 @@ def write_bench(root: Path) -> Path:
     for d in ("configs", "traffic", "limits"):
         (base / d).mkdir(parents=True)
     shutil.copytree(HARNESS / "metrics", base / "metrics")
+    shutil.copytree(HARNESS / "families", base / "families",
+                    ignore=shutil.ignore_patterns("__pycache__"))
     cfg = json.loads((HARNESS / "configs" / "starcoder2-3b.json").read_text())
     cfg.update(TINY)
     (base / "configs" / "tiny.json").write_text(json.dumps(cfg))
@@ -60,6 +62,7 @@ def control_readings(tiny, cell, seeds):
     """Readings of the program and of the float8 control on `seeds`, as
     `calibrate.py` takes them on the chip."""
     import calibrate
+    import family
     import run
     import traffic
 
@@ -67,11 +70,21 @@ def control_readings(tiny, cell, seeds):
     bench = json.loads((root / "BENCHMARK.json").read_text())
     cfg = json.loads((base / "configs" / "tiny.json").read_text())
     mix = traffic.load(base, run.entry(bench["workloads"], cell, "workload")["traffic"])
-    ctx = {"config": cfg, "mix": mix, "model_config": run.model_config(cfg)}
+    fam = family.load(base, cfg)
+    ctx = {"config": cfg, "mix": mix, "family": fam, "model_config": run.model_config(cfg, fam)}
     return calibrate.serve_readings(ctx, seeds, lambda *a: None)
 
 
+# the fault tests compare every request the window finishes, so that what they
+# compare does not depend on how many waves fit the window: of a sample of 4,
+# which 4 depends on that count, and with a stale cache the 4 drawn after 64,
+# 100, 112, 292, 312, 400, 524 or 624 requests all serve the reference's tokens
+COMPARE_ALL = {**WAVES, "check_requests": 10**6}
+
+
 def check_fault(tiny, capsys, monkeypatch, cell, fault, fails):
+    _, base = tiny
+    (base / "traffic" / "waves.json").write_text(json.dumps(COMPARE_ALL))
     fault(monkeypatch)
     rc, res = run_cell(tiny, capsys, cell, seconds=0.3)
     assert rc == 0 and res is not None

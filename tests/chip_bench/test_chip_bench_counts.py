@@ -1,13 +1,16 @@
-"""Operation, byte and parameter counts against the sizes worked out by hand,
-and the peak table's refusal of a chip it does not know."""
+"""Operation, byte and parameter counts of the dense family against the sizes
+worked out by hand, and the peak table's refusal of a chip it does not know."""
 
 import json
 
 import pytest
 from bench_fixtures import HARNESS
 
+import family
 import flops
 import peaks
+
+dense = family.load(HARNESS, {"reference": "dense"})
 
 
 def cfg(name):
@@ -16,49 +19,49 @@ def cfg(name):
 
 def test_parameters_of_the_configurations():
     # starcoder2-3b, head tied to the embedding: 30 x 95.9 M layer matrices + 151 M
-    assert flops.params(cfg("starcoder2-3b")) == pytest.approx(3.03e9, rel=2e-3)
-    assert flops.weight_bytes(cfg("starcoder2-3b")) == pytest.approx(6.06e9, rel=2e-3)
+    assert dense.params(cfg("starcoder2-3b")) == pytest.approx(3.03e9, rel=2e-3)
+    assert dense.weight_bytes(cfg("starcoder2-3b")) == pytest.approx(6.06e9, rel=2e-3)
     # minitron-8b-l16, 48 heads of 128 over 4096: 16 x 192.9 M layer matrices
     # (6.17 GB) + embedding and untied head (4.19 GB)
-    assert flops.layer_matmul_params(cfg("minitron-8b-l16")) == pytest.approx(192.9e6, rel=1e-3)
-    assert flops.weight_bytes(cfg("minitron-8b-l16")) == pytest.approx(10.37e9, rel=2e-3)
+    assert dense.layer_matmul_params(cfg("minitron-8b-l16")) == pytest.approx(192.9e6, rel=1e-3)
+    assert dense.weight_bytes(cfg("minitron-8b-l16")) == pytest.approx(10.37e9, rel=2e-3)
 
 
 def test_kv_bytes_per_token():
-    assert flops.kv_bytes_per_token(cfg("starcoder2-3b")) == 30 * 1024
-    assert flops.kv_bytes_per_token(cfg("minitron-8b-l16")) == 64 * 1024
+    assert dense.kv_bytes_per_token(cfg("starcoder2-3b")) == 30 * 1024
+    assert dense.kv_bytes_per_token(cfg("minitron-8b-l16")) == 64 * 1024
 
 
 def test_causal_attention_counts_half_the_square():
     c = cfg("starcoder2-3b")
-    mm_only = flops.prefill_flops(c, 1, 1024) - flops.attention_pairs_flops(c) * 1024 * 1025 / 2
-    assert mm_only == 2 * 1024 * c["num_layers"] * flops.layer_matmul_params(c) \
-        + 2 * flops.head_params(c)
+    mm_only = dense.prefill_flops(c, 1, 1024) - dense.attention_pairs_flops(c) * 1024 * 1025 / 2
+    assert mm_only == 2 * 1024 * c["num_layers"] * dense.layer_matmul_params(c) \
+        + 2 * dense.head_params(c)
     # one decode step attends pos + 1 keys, whatever the cache's length
-    step = flops.decode_flops(c, 1, 1023) - flops.decode_flops(c, 1, 1022)
-    assert step == flops.attention_pairs_flops(c)
+    step = dense.decode_flops(c, 1, 1023) - dense.decode_flops(c, 1, 1022)
+    assert step == dense.attention_pairs_flops(c)
 
 
 def test_decode_bytes_count_valid_positions_only():
     c = cfg("starcoder2-3b")
-    kv = flops.kv_bytes_per_token(c)
-    assert flops.decode_bytes(c, 16, 2047) - flops.decode_bytes(c, 16, 1023) == 16 * 1024 * kv
+    kv = dense.kv_bytes_per_token(c)
+    assert dense.decode_bytes(c, 16, 2047) - dense.decode_bytes(c, 16, 1023) == 16 * 1024 * kv
 
 
 @pytest.mark.parametrize("name,untied_table", [("starcoder2-3b", 0), ("minitron-8b-l16", 1)])
 def test_step_reads_every_weight_but_an_untied_embedding(name, untied_table):
     # a tied embedding is read whole by the head; an untied one only by the token's row
     c = cfg(name)
-    weights_read = flops.decode_bytes(c, 1, 0) - flops.kv_bytes_per_token(c) - 2 * c["d_model"]
-    assert weights_read == flops.weight_bytes(c) - untied_table * 2 * flops.head_params(c)
+    weights_read = dense.decode_bytes(c, 1, 0) - dense.kv_bytes_per_token(c) - 2 * c["d_model"]
+    assert weights_read == dense.weight_bytes(c) - untied_table * 2 * dense.head_params(c)
 
 
 def test_wave_roofline_is_bound_by_the_slower_resource():
     c, p = cfg("starcoder2-3b"), peaks.peaks("TPU v5 lite")
     prefill = flops.wave_roofline_s(c, 16, 1024, 0, p)
-    assert prefill == pytest.approx(flops.prefill_flops(c, 16, 1024) / p["bf16_flops"])
+    assert prefill == pytest.approx(dense.prefill_flops(c, 16, 1024) / p["bf16_flops"])
     step = flops.wave_roofline_s(c, 16, 1024, 1, p) - prefill
-    assert step == pytest.approx(flops.decode_bytes(c, 16, 1024) / p["hbm_bytes_per_s"])
+    assert step == pytest.approx(dense.decode_bytes(c, 16, 1024) / p["hbm_bytes_per_s"])
 
 
 def test_peak_table_refuses_an_unknown_chip():

@@ -8,21 +8,23 @@ from pathlib import Path
 
 import pytest
 
+import family
 import scopes
 
+DENSE = family.load(family.HERE, {"reference": "dense"}).SCOPES
 DATA = Path(__file__).resolve().parent / "data"
 MS = 1_000_000  # ns
 
 
 def test_scope_of_takes_the_deepest_named_scope():
     assert scopes.scope_of("jit(decode)/while/body/closed_call/attn/kv_write/"
-                           "dynamic_update_slice") == "attn/kv_write"
-    assert scopes.scope_of("jit(prefill)/while/body/closed_call/mlp/dot_general") == "mlp"
-    assert scopes.scope_of("jit(decode)/while/body/closed_call/attn/convert") == "attn"
-    assert scopes.scope_of("attn/core/reduce_max") == "attn/core"
+                           "dynamic_update_slice", DENSE) == "attn/kv_write"
+    assert scopes.scope_of("jit(prefill)/while/body/closed_call/mlp/dot_general", DENSE) == "mlp"
+    assert scopes.scope_of("jit(decode)/while/body/closed_call/attn/convert", DENSE) == "attn"
+    assert scopes.scope_of("attn/core/reduce_max", DENSE) == "attn/core"
     # a part of attention counts only inside `attn`, and the scan's own work in none
-    assert scopes.scope_of("jit(decode)/core/add") == scopes.UNSCOPED
-    assert scopes.scope_of("jit(decode)/while/body/dynamic_update_slice") == scopes.UNSCOPED
+    assert scopes.scope_of("jit(decode)/core/add", DENSE) == scopes.UNSCOPED
+    assert scopes.scope_of("jit(decode)/while/body/dynamic_update_slice", DENSE) == scopes.UNSCOPED
 
 
 def test_scope_map_reads_compiled_hlo_text():
@@ -37,7 +39,7 @@ def test_scope_map_reads_compiled_hlo_text():
         "  %copy.8 = bf16[8]{0} copy(%copy.7)",
         "}",
     ])
-    assert scopes.scope_map([text]) == {
+    assert scopes.scope_map([text], DENSE) == {
         "jit_decode": {"%fusion.4": "attn/kv_write", "%copy.7": scopes.UNSCOPED,
                        "%copy.8": scopes.UNSCOPED}}
 
@@ -188,7 +190,7 @@ def test_engine_trace_recorded_on_the_chip(tmp_path):
     hlo = gzip.open(DATA / "tpu_v5e_engine.hlo.txt.gz", "rt").read()
     path = tmp_path / "engine.xplane.pb"
     path.write_bytes(gzip.open(DATA / "tpu_v5e_engine.xplane.pb.gz").read())
-    smap = scopes.scope_map([hlo])
+    smap = scopes.scope_map([hlo], DENSE)
     got = scopes.reduce_xplane(str(path), smap)
 
     assert got["spans"] == {"engine.admit": 1, "engine.prefill": 1, "engine.sample": 3,
@@ -227,3 +229,81 @@ def test_engine_trace_recorded_on_the_chip(tmp_path):
     ran_products = {(p, n) for p, n in ran if n in products.get(p, ())}
     assert {p for p, _ in ran_products} == {"jit_prefill", "jit_decode"}
     assert all(smap[p][n] != scopes.UNSCOPED for p, n in ran_products), ran_products
+
+
+def recorded_engine(tmp_path):
+    """The recorded engine trace reduced by the dense family's scopes, and its
+    scope map."""
+    hlo = gzip.open(DATA / "tpu_v5e_engine.hlo.txt.gz", "rt").read()
+    path = tmp_path / "engine.xplane.pb"
+    path.write_bytes(gzip.open(DATA / "tpu_v5e_engine.xplane.pb.gz").read())
+    smap = scopes.scope_map([hlo], DENSE)
+    return scopes.reduce_xplane(str(path), smap), smap
+
+
+def test_dense_scopes_map_the_recorded_engine_as_before(tmp_path):
+    import hashlib
+    import json
+
+    _, smap = recorded_engine(tmp_path)
+    # the map that the scopes fixed in `scopes.py` gave before families named them
+    assert {k: len(v) for k, v in smap.items()} == {"jit_prefill": 665, "jit_decode": 652}
+    digest = hashlib.sha256(json.dumps(smap, sort_keys=True).encode()).hexdigest()[:16]
+    assert digest == "0145fc3a43afb15e"
+
+
+def test_a_family_declares_its_own_scopes():
+    text = "\n".join([
+        "HloModule jit_decode, is_scheduled=true",
+        "ENTRY %main.3 (p: bf16[8]) -> bf16[8] {",
+        '  %fusion.1 = bf16[8]{0} fusion(%p), metadata={op_name="jit(decode)/while/body/'
+        'closed_call/moe/router/dot_general"}',
+        '  %fusion.2 = bf16[8]{0} fusion(%p), metadata={op_name="jit(decode)/while/body/'
+        'closed_call/moe/experts/router/dot_general"}',
+        '  ROOT %fusion.3 = bf16[8]{0} fusion(%p), metadata={op_name="jit(decode)/moe/attn/core"}',
+        "}",
+    ])
+    moe = DENSE + ("moe", "moe/router", "moe/experts")
+    assert scopes.scope_map([text], moe) == {"jit_decode": {
+        "%fusion.1": "moe/router", "%fusion.2": "moe/router", "%fusion.3": "attn/core"}}
+    assert scopes.scope_map([text], DENSE) == {"jit_decode": {
+        "%fusion.1": scopes.UNSCOPED, "%fusion.2": scopes.UNSCOPED, "%fusion.3": "attn/core"}}
+
+
+WAVE = {"traced": False, "decode_steps": 127, "sample_s": 0.1, "dispatch_s": 0.05}
+
+
+@pytest.mark.parametrize("name,program,part,spans", [
+    ("decode.attn_core_ms", "jit_decode", "attn/core", None),
+    ("prefill.attn_core_ms", "jit_prefill", "attn/core", None),
+    ("prefill.mlp_ms", "jit_prefill", "mlp", None),
+    ("idle.fetch_ms", "jit_decode", None, ("engine.fetch",)),
+    ("idle.host_ms", "jit_decode", None, ("engine.sample", "engine.dispatch")),
+])
+def test_scope_readers_on_the_recorded_engine(tmp_path, name, program, part, spans):
+    import run
+    from bench_fixtures import HARNESS
+
+    got, _ = recorded_engine(tmp_path)
+    read = run.load_reader(HARNESS, name)
+    prog = got["programs"][program]
+    secs = prog["scopes"][part] if part else sum(got["idle"].get(s, 0.0) for s in spans)
+    assert secs > 0
+    assert read({"scopes": got, "waves": [WAVE]}) == pytest.approx(1e3 * secs / prog["runs"])
+    assert read({"scopes": None, "waves": [WAVE]}) is None
+    assert read({"waves": [WAVE]}) is None
+    # a program that writes no engine spans, or no such scope
+    bare = {**got, "spans": {}, "programs": {n: {**p, "scopes": {}}
+                                             for n, p in got["programs"].items()}}
+    assert read({"scopes": bare}) is None
+
+
+def test_host_step_reader_needs_the_engine_counters():
+    import run
+    from bench_fixtures import HARNESS
+
+    read = run.load_reader(HARNESS, "engine.host_step_ms")
+    traced = {**WAVE, "traced": True, "sample_s": 9.0}
+    assert read({"waves": [traced, WAVE, WAVE]}) == pytest.approx(1e3 * 0.15 / 127)
+    assert read({"waves": [WAVE, {**WAVE, "sample_s": None}]}) is None
+    assert read({"waves": [traced]}) is None
