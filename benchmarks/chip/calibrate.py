@@ -64,7 +64,7 @@ def serve_readings(ctx, seeds, log):
             t0 = time.perf_counter()
             for w in range(waves):
                 wave = [Request(prompt=p, max_new_tokens=new)
-                        for p in traffic.prompts(seed, w, B, S, V)]
+                        for p in traffic.prompts(seed, w, B, S, V, mix.get("topic_ids"))]
                 engine.serve(wave)
                 reqs += wave
             wave_s = (time.perf_counter() - t0) / waves

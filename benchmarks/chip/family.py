@@ -21,6 +21,16 @@ no other file of the harness changes. A family module holds:
 What the family implements of a cut configuration, such as a sliced
 vocabulary or a chip's share of the experts, is the family's and its
 configuration's business: the harness hands both the configuration's dict.
+
+A configuration file may give a section of the program's config as a mapping,
+such as `"moe": {"num_experts": 8, "top_k": 6}`: the program takes it field by
+field onto the arch's own section (`run.model_config`), and `COVERS` is held
+against the section so built. A family reads a section as the file gives it,
+`cfg["moe"]["top_k"]` in `layout`, and its jitted reference reads the
+section's numbers and names under dotted names, `"moe.top_k"`, in the static
+argument (`reference.static`); so the file states every field of a section
+that its family reads. The keys in `META` are no fields of the program's
+config, and no sections.
 """
 
 from __future__ import annotations
@@ -33,6 +43,15 @@ HERE = Path(__file__).resolve().parent
 NAME = re.compile(r"[A-Za-z0-9_][A-Za-z0-9_.\-]*")
 
 _loaded: dict = {}  # name -> the module `load` last found for it
+
+# keys of a configuration file that are not fields of the program's config
+META = ("source", "arch", "deployment", "reduced", "assumed", "departures", "compile_rehearsal",
+        "reference")
+
+
+def sections(cfg: dict) -> dict[str, dict]:
+    """The program-config sections a configuration file gives, {name: {field: value}}."""
+    return {k: v for k, v in cfg.items() if k not in META and isinstance(v, dict)}
 
 
 def load_file(path: Path, module_name: str):

@@ -18,6 +18,8 @@ import math
 import jax
 import jax.numpy as jnp
 
+import family
+
 HIGHEST = jax.lax.Precision.HIGHEST
 Q_BLOCK = 128  # query rows per attention block
 FP8_MAX = 448.0
@@ -90,5 +92,9 @@ def attention(cfg, h, wq, wk, wv, wo, mode):
 
 def static(cfg: dict) -> tuple:
     """The numbers and names of a configuration's dict, hashable: a jitted
-    reference takes them as a static argument."""
-    return tuple(sorted((k, v) for k, v in cfg.items() if isinstance(v, (int, float, str))))
+    reference takes them as a static argument. Those of a program-config
+    section (`family.sections`) come under dotted names, `"moe.top_k"`."""
+    items = [(k, v) for k, v in cfg.items() if isinstance(v, (int, float, str))]
+    items += [(f"{name}.{k}", v) for name, given in family.sections(cfg).items()
+              for k, v in given.items() if isinstance(v, (int, float, str))]
+    return tuple(sorted(items))

@@ -8,7 +8,9 @@ one configuration, traffic mix, metric or cell is a file of its own here,
 found by its name:
 
   configs/<config>.json    the sizes as run, with source, cuts and departures, and
-                           the family it names (`"reference"`)
+                           the family it names (`"reference"`); a section of the
+                           program's config as a mapping of its fields
+                           (`"moe": {"num_experts": 8, ...}`), `null` for none
   families/<family>.py     what is specific to one architecture: the sizes it
                            covers, its parameter tree, its plain reference, its
                            work counts and its named scopes (`family.py`)
@@ -37,16 +39,14 @@ import os  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
-from dataclasses import replace  # noqa: E402
+from dataclasses import fields, is_dataclass, replace  # noqa: E402
 from pathlib import Path  # noqa: E402
 
 HERE = Path(__file__).resolve().parent
 ROOT = HERE.parents[1]
 sys.path.insert(0, str(HERE))
 
-# keys of a configuration file that are not sizes of the model
-META = ("source", "arch", "deployment", "reduced", "assumed", "departures", "compile_rehearsal",
-        "reference")
+import family  # noqa: E402
 
 
 def log(*a):
@@ -78,15 +78,26 @@ def applies(metric: dict, cell: str, e2e_names=None) -> bool:
 
 
 def model_config(cfg: dict, fam):
-    """The program's config for the file's arch, with every size of the file;
+    """The program's config for the file's arch, with every size of the file,
+    a section (`family.sections`) applied field by field onto the arch's own;
     refused where the family `fam` does not cover it."""
     from repro.configs import get_config
 
     base = get_config(cfg["arch"])
-    sizes = {k: v for k, v in cfg.items() if k not in META}
+    sizes = {k: v for k, v in cfg.items() if k not in family.META}
     unknown = [k for k in sizes if not hasattr(base, k)]
     if unknown:
         raise KeyError(f"configuration keys the program does not have: {unknown}")
+    for name, given in family.sections(cfg).items():
+        own = getattr(base, name)
+        if not is_dataclass(own):
+            raise KeyError(f"configuration section {name!r}: the program config of "
+                           f"{cfg['arch']!r} has no such section ({name} = {own!r})")
+        unknown = [k for k in given if k not in {f.name for f in fields(own)}]
+        if unknown:
+            raise KeyError(f"configuration section {name!r}: fields the program does not "
+                           f"have: {unknown}")
+        sizes[name] = replace(own, **given)
     mcfg = replace(base, **sizes)
     off = {k: getattr(mcfg, k) for k, v in fam.COVERS.items() if getattr(mcfg, k) != v}
     if off:
@@ -95,8 +106,6 @@ def model_config(cfg: dict, fam):
 
 
 def load_reader(base: Path, name: str):
-    import family
-
     return family.load_file(base / "metrics" / f"{name}.py", f"metric_{name}").read
 
 
@@ -190,7 +199,6 @@ def main(argv=None, *, root: Path = ROOT, base: Path = HERE, require_tpu: bool =
     cell = entry(bench["workloads"], args.workload, "workload")
     cfg_entry = entry(bench["configs"], cell["config"], "config")
     cfg = json.loads((root / cfg_entry["file"]).read_text())
-    import family
     import traffic
 
     fam = family.load(base, cfg)

@@ -9,6 +9,13 @@ prefill returns (`engine.prefill_s` later). With `--trace 1` the profiler runs
 over the first whole waves of `TRACE_SECONDS` or more; each wave records
 whether it was traced, so that host-clock readers can leave those out.
 
+Each wave's record holds every public number the engine holds after its
+`serve()` (`counters`): `prefill_s`, `decode_s`, `decode_steps`, `sample_s`,
+`dispatch_s` and whatever counter the program adds, so that a new
+`metrics/<name>.py` reads a new counter with no edit here; beside them the
+harness's own `t0`, `t1`, `traced` and `tokens`, which win over a counter of
+the same name.
+
 After the window the program's state is freed and the plain reference of the
 configuration's family (`families/<name>.py`) reads a sample of the finished
 requests drawn from the seed: for each served token, how far its logit lies
@@ -20,6 +27,7 @@ window, from which `run.py` maps device time to the family's named scopes.
 from __future__ import annotations
 
 import gc
+import numbers
 import sys
 import time
 
@@ -45,6 +53,12 @@ def prediction(mcfg, mix) -> dict:
                           gen=mix["new_tokens"])
     return {"ttft_ms": b.ttft * 1e3, "tpot_ms": b.tpot * 1e3, "wave_s": b.total,
             "gen_tokens_per_s": mix["slots"] * mix["new_tokens"] / b.total}
+
+
+def counters(engine) -> dict:
+    """Every public int or float attribute of `engine`."""
+    return {k: v for k, v in vars(engine).items()
+            if not k.startswith("_") and isinstance(v, numbers.Real) and not isinstance(v, bool)}
 
 
 def memory_analysis(engine, model, mix, with_text: bool = False) -> tuple[dict, list[str]]:
@@ -102,16 +116,13 @@ def run(ctx) -> dict:
     w0, cpu0 = time.perf_counter(), time.process_time()
     while True:
         reqs = [Request(prompt=p, max_new_tokens=new)
-                for p in traffic.prompts(seed, len(waves), B, S, V)]
+                for p in traffic.prompts(seed, len(waves), B, S, V, mix.get("topic_ids"))]
         traced = tracer.running
         t0 = time.perf_counter()
         with jax.profiler.TraceAnnotation("wave"):
             engine.serve(reqs)
         t1 = time.perf_counter()
-        waves.append({"t0": t0, "t1": t1, "traced": traced, "prefill_s": engine.prefill_s,
-                      "decode_s": engine.decode_s, "decode_steps": engine.decode_steps,
-                      "sample_s": getattr(engine, "sample_s", None),
-                      "dispatch_s": getattr(engine, "dispatch_s", None),
+        waves.append({**counters(engine), "t0": t0, "t1": t1, "traced": traced,
                       "tokens": [len(r.out_tokens) for r in reqs]})
         finished += [(r.prompt, list(r.out_tokens), r.done) for r in reqs]
         log(f"wave {len(waves) - 1}: {t1 - t0:.4f} s, prefill {engine.prefill_s:.4f} s, "

@@ -9,6 +9,14 @@ tree the program reads, as {path: (shape, dtype name)}; the harness refuses to
 run when the program's own tree differs from it. Every leaf is drawn from its
 own key, and each layer of a stacked leaf (`layers/...`) from a key of its
 own, so that one layer can be drawn alone.
+
+Leaves are drawn per layer, without the stacking axis. A leaf of rank 2 or
+more is a matrix, or a stack of matrices such as a layer's experts (E, d_in,
+d_out): it is drawn at std 1/sqrt(`shape[-2]`), its input axis behind any
+leading axes, and each leading index (each expert) is a matrix drawn in its
+own right, from a key of its own. A rank-1 leaf that is no norm scale or bias
+keeps std 1/sqrt(`shape[0]`). `leaf` and `layer_leaf` give the same values
+for the same layer.
 """
 
 from __future__ import annotations
@@ -40,9 +48,10 @@ def _leaf_key(key, path: str):
 
 
 def draw(key, path: str, shape: tuple[int, ...], tied: bool = False) -> jax.Array:
-    """One matrix or vector of `path` (without its layer axis), in float32. A
-    tied embedding is the head too, and takes the head's std 1/sqrt(d_model):
-    at std 1 its logits would favour the current token by far."""
+    """One matrix, vector or stack of matrices of `path` (without its layer
+    axis), in float32. A tied embedding is the head too, and takes the head's
+    std 1/sqrt(d_model): at std 1 its logits would favour the current token by
+    far."""
     name = path.rsplit("/", 1)[-1]
     if name == "scale":
         std, mean = NORM_STD, 1.0
@@ -50,6 +59,9 @@ def draw(key, path: str, shape: tuple[int, ...], tied: bool = False) -> jax.Arra
         std, mean = NORM_STD, 0.0
     elif path == "embed/tok":
         std, mean = (shape[1] ** -0.5 if tied else 1.0), 0.0
+    elif len(shape) > 2:  # a stack of matrices: each from a key of its own
+        index = jnp.arange(shape[0], dtype=jnp.uint32)
+        return jax.vmap(lambda i: draw(jax.random.fold_in(key, i), path, shape[1:]))(index)
     else:
         std, mean = shape[0] ** -0.5, 0.0
     half = std * 3.0**0.5
